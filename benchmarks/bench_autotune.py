@@ -84,7 +84,7 @@ def run(smoke: bool, cache_path=None) -> dict:
     for name, a, n in _cases(smoke):
         a = a.ensure_nonempty_rows()
         fp = autotune.fingerprint_bcsr(a, n)
-        choice, timings = tuner.tune(a, n, iters=iters)
+        choice, timings = tuner.tune(a, n, iters=iters, interpret=True)
         cached = tuner.get(fp)  # what backend="auto" dispatch will use
         tuned_label = f"{cached.variant}/bn{cached.bn}"
         # re-time default and the cached pick in a fresh pass (not the
@@ -108,7 +108,8 @@ def run(smoke: bool, cache_path=None) -> dict:
             "default_us": round(default_s * 1e6, 2) if default_s else None,
             "tuned_us": round(tuned_s * 1e6, 2) if tuned_s else None,
             "speedup_vs_default": round(speedup, 3),
-            "timings_us": {k: round(v * 1e6, 2) for k, v in timings.items()},
+            "timings_us": {k: v if isinstance(v, str) else round(v * 1e6, 2)
+                           for k, v in timings.items()},
         }
         rows.append(row)
         print(f"{name:>18}: {tuned_label:<16} "

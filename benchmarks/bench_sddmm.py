@@ -74,7 +74,8 @@ def run(smoke: bool = True, cache_path=None) -> dict:
     for name, a, n in _cases(smoke):
         a = a.ensure_nonempty_rows()
         fp = autotune.fingerprint_bcsr(a, n, op="sddmm")
-        choice, timings = tuner.tune(a, n, op="sddmm", iters=3)
+        choice, timings = tuner.tune(a, n, op="sddmm", iters=3,
+                                     interpret=True)
         cached = tuner.get(fp)
         arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32)
         rng = np.random.default_rng(0)
@@ -98,7 +99,8 @@ def run(smoke: bool = True, cache_path=None) -> dict:
             "default_us": round(default_s * 1e6, 2),
             "tuned_us": round(tuned_s * 1e6, 2),
             "speedup_vs_default": round(speedup, 3),
-            "timings_us": {k: round(v * 1e6, 2) for k, v in timings.items()},
+            "timings_us": {k: v if isinstance(v, str) else round(v * 1e6, 2)
+                           for k, v in timings.items()},
         }
         rows.append(row)
         print(f"{name:>18}: {cached.variant}/bn{cached.bn} "

@@ -121,6 +121,8 @@ def main() -> int:
                          "--figures bench_kernels")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.figures is not None:
         run_figures(args.figures or None)
         return 0

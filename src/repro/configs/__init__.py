@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 from repro.configs.base import SHAPES, ModelConfig, ShapeCell, cell_applicable
-from repro.configs.archs import ARCHS, smoke_config
+from repro.configs.archs import ARCHS, smoke_config, xla_lowered
 
 
 def get_config(name: str) -> ModelConfig:

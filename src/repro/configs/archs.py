@@ -115,30 +115,29 @@ _register(ModelConfig(
     name="smat-ffn-1.3b", family="dense", layout="attn_mlp",
     n_layers=24, d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128,
     d_ff=8192, vocab_size=32000,
-    ffn_sparsity=SparsitySpec(density=0.10, block=(128, 128), backend="xla"),
+    ffn_sparsity=SparsitySpec(density=0.10, block=(128, 128), backend="auto"),
 ))
 
 # Both sparse workloads at once: block-sparse FFN weights AND block-sparse
 # attention scores (banded mask, SDDMM -> block softmax -> SpMM).  The
 # banded mask bounds the attended window, so this arch qualifies for the
-# 500k decode cell like the SWA archs do.  backend="xla" mirrors the
-# ffn_sparsity spec above: the registered config must stay CPU-lowerable
-# for the whole-fleet dryrun (backend="auto" can resolve to a
-# non-interpret Pallas variant there); flip to "auto" on real TPUs.
+# 500k decode cell like the SWA archs do.
 _register(ModelConfig(
     name="smat-attn-1.3b", family="dense", layout="attn_mlp",
     n_layers=24, d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128,
     d_ff=8192, vocab_size=32000,
-    ffn_sparsity=SparsitySpec(density=0.10, block=(128, 128), backend="xla"),
+    ffn_sparsity=SparsitySpec(density=0.10, block=(128, 128), backend="auto"),
     attn_sparsity=AttnSparsitySpec(mask=banded(4096), block=(128, 128),
-                                   backend="xla"),
+                                   backend="auto"),
 ))
 
 
 # ---------------------------------------------------------------- smoke view
 def smoke_config(cfg: ModelConfig) -> ModelConfig:
     """Reduced same-family config for CPU smoke tests: few layers, small
-    width, tiny vocab; one forward/train step must run and be NaN-free."""
+    width, tiny vocab; one forward/train step must run and be NaN-free.
+    Sparse specs run the ``xla`` backend here: the smoke view is the CPU
+    test path, where no Pallas kernel is compiled."""
     kw = dict(
         name=cfg.name + ":smoke",
         n_layers=2 if cfg.layout != "gemma_pair" else 2,
@@ -167,10 +166,22 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         kw.update(patch_tokens=8)
     if cfg.ffn_sparsity is not None:
         kw.update(ffn_sparsity=SparsitySpec(
-            density=0.3, block=(16, 16), backend=cfg.ffn_sparsity.backend,
+            density=0.3, block=(16, 16), backend="xla",
             bn=128, interpret=True))
     if cfg.attn_sparsity is not None:
         kw.update(attn_sparsity=dataclasses.replace(
             cfg.attn_sparsity, mask=banded(32), block=(16, 16),
-            bn=128, interpret=True))
+            backend="xla", bn=128, interpret=True))
     return dataclasses.replace(cfg, **kw)
+
+
+def xla_lowered(cfg: ModelConfig) -> ModelConfig:
+    """``cfg`` with every sparse spec on the ``xla`` backend: the CPU
+    dry-run's stand-in for the chip's Pallas kernels, and the reference
+    a chip run compares its kernels against."""
+    kw = {}
+    for field in ("ffn_sparsity", "attn_sparsity"):
+        spec = getattr(cfg, field)
+        if spec is not None and spec.backend != "xla":
+            kw[field] = dataclasses.replace(spec, backend="xla")
+    return dataclasses.replace(cfg, **kw) if kw else cfg

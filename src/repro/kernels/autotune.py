@@ -25,8 +25,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import time
-from typing import Callable, Dict, Iterable, Optional, Tuple
+from typing import Callable, Dict, Iterable, Optional, Tuple, Union
 
 import numpy as np
 
@@ -486,6 +485,45 @@ def shard_entry_key(fp: Fingerprint, max_shards: int) -> str:
     return f"shards|max={int(max_shards)}|{fp.key()}"
 
 
+# ------------------------------------------------------------ timed sweeps
+# a sweep's timings: seconds per candidate, or "failed: <ExceptionType>"
+Timings = Dict[str, Union[float, str]]
+
+
+def time_candidate(timings: Timings, label: str, fn, *operands,
+                   warmup: int, iters: int, **where) -> None:
+    """Time one sweep candidate into ``timings[label]`` (median seconds).
+
+    A candidate that raises is recorded, not skipped: ``timings[label]``
+    becomes ``"failed: <ExceptionType>"`` and an
+    ``autotune.candidate_failed`` event names it with the message."""
+    try:
+        timings[label] = obs_metrics.timeit(
+            fn, *operands, warmup=max(warmup, 1), iters=iters)
+    except Exception as e:  # recorded in the sweep result, not skipped
+        timings[label] = f"failed: {type(e).__name__}"
+        obs_trace.event("autotune.candidate_failed", candidate=label,
+                        error=type(e).__name__, message=str(e)[:500],
+                        **where)
+        obs_metrics.counter("autotune.candidate_failed").inc()
+
+
+def measured_winner(timings: Timings, default_label: str) -> str:
+    """Fastest measured label; the default wins ties within noise (2%).
+    Raises when the default itself failed: a pick nothing measured
+    against the baseline is never cached as ``measured``."""
+    measured = {k: v for k, v in timings.items() if not isinstance(v, str)}
+    if default_label not in measured:
+        raise RuntimeError(
+            f"autotune sweep: the default candidate {default_label} did "
+            f"not run ({timings.get(default_label, 'not swept')}); "
+            f"timings: {timings}")
+    best = min(measured, key=measured.get)
+    if measured[default_label] <= measured[best] * 1.02:
+        best = default_label
+    return best
+
+
 # ----------------------------------------------------------------- autotuner
 class Autotuner:
     """Fingerprint -> KernelChoice cache with analytic and measured fills.
@@ -633,19 +671,24 @@ class Autotuner:
 
     # ------------------------------------------------------------- tuning
     def tune(self, a: bcsr_lib.BCSR, n: int, *, dtype=jnp.float32,
-             interpret: bool = True, variants: Optional[Iterable[str]] = None,
+             interpret: bool = False,
+             variants: Optional[Iterable[str]] = None,
              warmup: int = 1, iters: int = 3, rng_seed: int = 0,
              reorder: str = "identity",
              reorder_granularity: str = "element",
              n_shards: int = 8,
-             op: str = "spmm") -> Tuple[KernelChoice, Dict[str, float]]:
+             op: str = "spmm") -> Tuple[KernelChoice, Timings]:
         """Timed micro-sweep over the ``op`` family's (variant, bn)
         candidates.
 
         Always measures the family's hardcoded default (``nnz_stream`` /
         ``sddmm_stream``, bn=512) so the winner is never slower than it;
-        returns (choice, {candidate: sec}).  The winner is cached (and
-        persisted) under the matrix's v7 ``op=``-scoped fingerprint.
+        returns (choice, {candidate: sec}).  A candidate that raises is
+        recorded as ``"failed: <ExceptionType>"`` in those timings; if the
+        default fails, ``tune`` raises and caches nothing.  The winner is
+        cached (and persisted) under the matrix's v7 ``op=``-scoped
+        fingerprint.  ``interpret=True`` times the Pallas interpreter (the
+        CPU test path) — never what a chip runs.
         ``reorder`` mirrors the ``prepare_sparse`` arguments so the sweep
         measures (and the fingerprint matches) the permuted structure the
         apply path will actually dispatch on.  For ``op="sddmm"`` the
@@ -691,40 +734,25 @@ class Autotuner:
         dv = default_variant(op)
         cand.setdefault(f"{dv}/bn{DEFAULT_BN}", (dv, DEFAULT_BN))
 
-        timings: Dict[str, float] = {}
+        timings: Timings = {}
         with obs_trace.span("autotune.tune", key=fp.key(), op=op,
                             n_candidates=len(cand)):
             for label, (name, bn) in cand.items():
-                fn = _mk_fn(get_variant(name).backend, bn)
-                try:
-                    jax.block_until_ready(fn(*operands))
-                    for _ in range(max(warmup - 1, 0)):
-                        jax.block_until_ready(fn(*operands))
-                    ts = []
-                    for _ in range(iters):
-                        t0 = time.perf_counter()
-                        jax.block_until_ready(fn(*operands))
-                        ts.append(time.perf_counter() - t0)
-                    timings[label] = float(np.median(ts))
-                except Exception:  # variant not runnable — skip, don't die
-                    continue
+                time_candidate(
+                    timings, label, _mk_fn(get_variant(name).backend, bn),
+                    *operands, warmup=warmup, iters=iters, key=fp.key(),
+                    op=op)
 
-        default_label = f"{dv}/bn{DEFAULT_BN}"
-        if not timings:
-            choice = default_choice(op)
-        else:
-            best_label = min(timings, key=timings.get)
-            # prefer the default on a tie within noise (2%)
-            if (default_label in timings and
-                    timings[default_label] <= timings[best_label] * 1.02):
-                best_label = default_label
-            name, bn = cand[best_label]
-            choice = KernelChoice(name, bn, source="measured",
-                                  predicted_us=timings[best_label] * 1e6)
+        best_label = measured_winner(timings, f"{dv}/bn{DEFAULT_BN}")
+        name, bn = cand[best_label]
+        choice = KernelChoice(name, bn, source="measured",
+                              predicted_us=timings[best_label] * 1e6)
         self.put(fp, choice, persist=True)
         obs_trace.event("autotune.tuned", key=fp.key(), op=op,
                         variant=choice.variant, bn=choice.bn,
-                        n_candidates=len(timings))
+                        n_candidates=len(timings),
+                        n_failed=sum(isinstance(v, str)
+                                     for v in timings.values()))
         obs_metrics.counter("autotune.tuned", op=op).inc()
         return choice, timings
 

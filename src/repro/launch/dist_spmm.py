@@ -67,11 +67,6 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-try:  # moved to the public namespace on newer JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # pragma: no cover - newer JAX
-    _shard_map = jax.shard_map
-
 from repro.core import bcsr as bcsr_lib
 from repro.core import permute as permute_lib
 from repro.kernels import ops
@@ -205,13 +200,6 @@ def chunk_schedule(n: int, n_chunks: int) -> Tuple[Tuple[int, int], ...]:
     return tuple(bounds)
 
 
-def _barrier(x: jnp.ndarray) -> jnp.ndarray:
-    try:
-        return jax.lax.optimization_barrier(x)
-    except AttributeError:      # pragma: no cover - very old JAX
-        return x
-
-
 @jax.custom_vjp
 def _stage(x: jnp.ndarray) -> jnp.ndarray:
     """Pin the ISSUE point of a chunk's operand movement.
@@ -223,11 +211,11 @@ def _stage(x: jnp.ndarray) -> jnp.ndarray:
     VJP passes the cotangent straight through (the barrier has no
     differentiation rule; the chunked forward's real backward runs the
     SINGLE-SHOT path anyway, see ``spmm_sharded``)."""
-    return _barrier(x)
+    return jax.lax.optimization_barrier(x)
 
 
 def _stage_fwd(x):
-    return _barrier(x), None
+    return jax.lax.optimization_barrier(x), None
 
 
 def _stage_bwd(_, g):
@@ -848,9 +836,9 @@ def _spmm_sharded_exec(arrays: ShardedArrays, smeta: ShardedMeta,
     shard_spec = P(AXIS_ROW)
     b_spec = P(None, AXIS_COL) if C > 1 else P()
     out_spec = P(AXIS_ROW, AXIS_COL) if C > 1 else P(AXIS_ROW)
-    f = _shard_map(body, mesh=mesh,
-                   in_specs=(P(),) + (shard_spec,) * 7 + (b_spec,),
-                   out_specs=out_spec, check_rep=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P(),) + (shard_spec,) * 7 + (b_spec,),
+                      out_specs=out_spec, check_vma=False)
     out_pad = f(vals_ext, arrays.src_index, arrays.row_ids, arrays.col_ids,
                 arrays.real_mask, arrays.t_perm, arrays.t_row_ids,
                 arrays.t_col_ids, b_p)
@@ -864,16 +852,16 @@ def _spmm_sharded_exec(arrays: ShardedArrays, smeta: ShardedMeta,
 
 # ------------------------------------------------------------------- tuning
 def tune_shards(arrays: ShardedArrays, smeta: ShardedMeta, n: int, *,
-                interpret: bool = True, warmup: int = 1, iters: int = 3,
+                interpret: bool = False, warmup: int = 1, iters: int = 3,
                 rng_seed: int = 0, tuner=None) -> dict:
     """Timed per-shard micro-sweep (the sharded analogue of
     ``Autotuner.tune``): times every registered candidate on each shard's
     LOCAL slice and caches the winner under the shard's v7 fingerprint,
     so later ``backend="auto"`` dispatch picks measured winners per shard.
     Shards whose fingerprints coincide (well-balanced partitions — the
-    common case) are timed once.  Returns {fingerprint_key: choice}."""
-    import time
-
+    common case) are timed once.  Failed candidates are recorded like
+    ``Autotuner.tune`` records them, and a shard whose default candidate
+    fails raises.  Returns {fingerprint_key: choice}."""
     from repro.kernels import autotune
     tuner = tuner or autotune.get_autotuner()
     rng = np.random.default_rng(rng_seed)
@@ -903,34 +891,18 @@ def tune_shards(arrays: ShardedArrays, smeta: ShardedMeta, n: int, *,
         cand.setdefault(
             f"{autotune.DEFAULT_VARIANT}/bn{autotune.DEFAULT_BN}",
             (autotune.DEFAULT_VARIANT, autotune.DEFAULT_BN))
-        timings = {}
+        timings: autotune.Timings = {}
         for label, (name, bn) in cand.items():
             backend = autotune.get_variant(name).backend
             fn = jax.jit(lambda bb, _be=backend, _bn=bn: ops.spmm(
                 arr, meta_s, bb, backend=_be, bn=_bn, interpret=interpret))
-            try:
-                jax.block_until_ready(fn(b))
-                for _ in range(max(warmup - 1, 0)):
-                    jax.block_until_ready(fn(b))
-                ts = []
-                for _ in range(iters):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(fn(b))
-                    ts.append(time.perf_counter() - t0)
-                timings[label] = float(np.median(ts))
-            except Exception:   # variant not runnable here — skip, not die
-                continue
-        default_label = f"{autotune.DEFAULT_VARIANT}/bn{autotune.DEFAULT_BN}"
-        if not timings:
-            choice = autotune.default_choice()
-        else:
-            best = min(timings, key=timings.get)
-            if (default_label in timings and
-                    timings[default_label] <= timings[best] * 1.02):
-                best = default_label          # default wins ties (noise)
-            name, bn = cand[best]
-            choice = autotune.KernelChoice(name, bn, source="measured",
-                                           predicted_us=timings[best] * 1e6)
+            autotune.time_candidate(timings, label, fn, b, warmup=warmup,
+                                    iters=iters, key=fp.key(), shard=s)
+        best = autotune.measured_winner(
+            timings, f"{autotune.DEFAULT_VARIANT}/bn{autotune.DEFAULT_BN}")
+        name, bn = cand[best]
+        choice = autotune.KernelChoice(name, bn, source="measured",
+                                       predicted_us=timings[best] * 1e6)
         tuner.put(fp, choice, persist=True)
         tuned[fp.key()] = choice
     return tuned
@@ -938,7 +910,7 @@ def tune_shards(arrays: ShardedArrays, smeta: ShardedMeta, n: int, *,
 
 def tune_shard_count(a: bcsr_lib.BCSR, n: int, *, max_shards: int = 8,
                      n_chunks: int = 1, backend: str = "auto", bn: int = 512,
-                     interpret: bool = True, warmup: int = 1, iters: int = 3,
+                     interpret: bool = False, warmup: int = 1, iters: int = 3,
                      rng_seed: int = 0, tuner=None):
     """Timed shard-count micro-sweep: the measured counterpart of
     :func:`resolve_n_shards` (the optional half of the autotune axis —
@@ -947,10 +919,9 @@ def tune_shard_count(a: bcsr_lib.BCSR, n: int, *, max_shards: int = 8,
     requested chunk depth, and caches the winner in the autotuner's
     shard-entry section under the operand's v7 ``nk=`` fingerprint so
     later ``resolve_n_shards`` calls return the measured choice.  Smaller
-    S wins ties (within 2% — partition overhead noise).  Returns the
-    ``ShardChoice``."""
-    import time
-
+    S wins ties (within 2% — partition overhead noise).  A candidate S
+    that fails to run is recorded (``autotune.time_candidate``); if none
+    runs, this raises.  Returns the ``ShardChoice``."""
     from repro.kernels import autotune
     tuner = tuner or autotune.get_autotuner()
     meta = ops.prepare_sparse_meta(a)
@@ -958,7 +929,7 @@ def tune_shard_count(a: bcsr_lib.BCSR, n: int, *, max_shards: int = 8,
     rng = np.random.default_rng(rng_seed)
     b = jnp.asarray(rng.standard_normal((a.shape[1], n)), jnp.float32)
 
-    timings = {}
+    timings: autotune.Timings = {}
     for s in autotune.shard_candidates(max_shards, meta.n_block_rows):
         try:
             sharr, smeta = prepare_sharded(a, s, dtype=jnp.float32)
@@ -967,27 +938,17 @@ def tune_shard_count(a: bcsr_lib.BCSR, n: int, *, max_shards: int = 8,
         fn = jax.jit(lambda bb, _a=sharr, _m=smeta: spmm_sharded(
             _a, _m, bb, backend=backend, bn=bn, interpret=interpret,
             n_chunks=n_chunks))
-        try:
-            jax.block_until_ready(fn(b))
-            for _ in range(max(warmup - 1, 0)):
-                jax.block_until_ready(fn(b))
-            ts = []
-            for _ in range(iters):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn(b))
-                ts.append(time.perf_counter() - t0)
-            timings[s] = float(np.median(ts))
-        except Exception:       # candidate not runnable here — skip
-            continue
-    if not timings:
-        choice = autotune.analytic_shard_choice(
-            meta, n, max_shards=max_shards, n_chunks=n_chunks)
-    else:
-        t_best = min(timings.values())
-        best = next(s for s in sorted(timings)
-                    if timings[s] <= t_best * 1.02)
-        choice = autotune.ShardChoice(best, source="measured",
-                                      predicted_us=timings[best] * 1e6)
+        autotune.time_candidate(timings, f"S{s}", fn, b, warmup=warmup,
+                                iters=iters, key=fp.key())
+    measured = {int(k[1:]): t for k, t in timings.items()
+                if not isinstance(t, str)}
+    if not measured:
+        raise RuntimeError(f"tune_shard_count: no shard count ran; "
+                           f"timings: {timings}")
+    t_best = min(measured.values())
+    best = next(s for s in sorted(measured) if measured[s] <= t_best * 1.02)
+    choice = autotune.ShardChoice(best, source="measured",
+                                  predicted_us=measured[best] * 1e6)
     tuner.put_shards(fp, max_shards, choice, persist=True)
     return choice
 

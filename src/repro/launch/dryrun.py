@@ -1,13 +1,11 @@
-import os
-os.environ["XLA_FLAGS"] = os.environ.get(
-    "DRYRUN_XLA_FLAGS",
-    "--xla_force_host_platform_device_count=512")
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell with
 ShapeDtypeStruct inputs (no allocation) and extract memory / cost / roofline.
 
-The two lines above MUST stay first — jax locks the device count on first
-init.  Everything below imports jax.
+A CPU-only shape and memory tool: ``main()`` sets ``XLA_FLAGS`` (from
+``DRYRUN_XLA_FLAGS``, default 512 virtual host devices) before JAX
+initializes its backend, and lowers every sparse kernel as
+``backend="xla"`` (no Pallas kernel compiles for the CPU).  Importing the
+module changes nothing.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-14b \
@@ -20,6 +18,7 @@ Small-mesh testing (CI):
 """
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -27,7 +26,8 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.configs import SHAPES, cell_applicable, get_config, list_archs
+from repro.configs import (SHAPES, cell_applicable, get_config, list_archs,
+                           xla_lowered)
 from repro.configs.base import ShapeCell
 from repro.launch import mesh as mesh_lib
 from repro.launch import roofline as rl
@@ -327,6 +327,9 @@ def paged_kv_report(cfg, cache_len: int = 512, n_slots: int = 4) -> dict:
 
 
 def main(argv=None):
+    # the device count locks when JAX first initializes its backend
+    os.environ["XLA_FLAGS"] = os.environ.get(
+        "DRYRUN_XLA_FLAGS", "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])
@@ -396,6 +399,12 @@ def main(argv=None):
                       f"{g['n_layers']} layers (paged={g['paged']}{extra})")
             records.append({"arch": cfg.name, "status": "paged_kv",
                             "paged_kv": kv_rep})
+        lowered_cfg = xla_lowered(cfg)
+        if lowered_cfg is not cfg:
+            print(f"[dryrun] {cfg.name}: sparse kernels lowered as "
+                  "backend='xla' (CPU shape/memory tool; the chip runs the "
+                  "configured Pallas kernels)")
+            cfg = lowered_cfg
         for s in shapes:
             cell = SHAPES[s]
             if args.batch or args.seq:

@@ -6,38 +6,16 @@ outer data-parallel axis (gradient reduction across pods rides the DCI).
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state; the dry-run sets XLA_FLAGS before any jax import.
-
-Version compat: ``jax.sharding.AxisType`` / the ``axis_types=`` kwarg only
-exist on newer JAX; on 0.4.x we fall back to ``jax.make_mesh`` without axis
-types, and on anything older still to a hand-built ``jax.sharding.Mesh``.
 """
 from __future__ import annotations
 
-import numpy as np
-
 import jax
-
-try:  # JAX >= 0.5-era explicit axis types
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - exercised on JAX 0.4.x
-    _AxisType = None
+from jax.sharding import AbstractMesh, AxisType
 
 
 def _build_mesh(shape, axes):
-    """jax.make_mesh with the newest supported signature."""
     shape, axes = tuple(shape), tuple(axes)
-    if hasattr(jax, "make_mesh"):
-        if _AxisType is not None:
-            try:
-                return jax.make_mesh(shape, axes,
-                                     axis_types=(_AxisType.Auto,) * len(axes))
-            except TypeError:  # make_mesh predates axis_types kwarg
-                pass
-        return jax.make_mesh(shape, axes)
-    # oldest fallback: arrange the flat device list ourselves
-    n = int(np.prod(shape))
-    devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -52,13 +30,8 @@ def make_mesh(shape, axes):
 
 
 def make_abstract_mesh(shape, axes):
-    """AbstractMesh across JAX versions: new API takes (shape, axis_names),
-    0.4.x takes a single ((name, size), ...) tuple."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:  # 0.4.x signature
-        return AbstractMesh(tuple(zip(tuple(axes), tuple(shape))))
+    """Device-free mesh of the given shape and axis names."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def data_axes(mesh) -> tuple:

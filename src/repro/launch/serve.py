@@ -1,19 +1,55 @@
-"""Serving launcher CLI: loads a (smoke-scale) model and runs continuous
-batched decode over a synthetic request stream, reporting tokens/s.
+"""Serving launcher CLI: loads a model with seeded random weights and runs
+continuous batched decode over a seeded synthetic request stream,
+reporting tokens/s.
 
   PYTHONPATH=src python -m repro.launch.serve --arch musicgen-medium:smoke \
       --requests 8 --new-tokens 16
+
+``make_requests`` and ``generate_all`` are the same path called
+in-process (``chip_smoke.py`` drives the full-width server through them).
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Dict, List, Tuple
 
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve.engine import Request, ServeEngine
+
+
+def make_requests(cfg, n_requests: int, prompt_len: int, new_tokens: int,
+                  temperature: float = 0.0, seed: int = 0) -> List[Request]:
+    """``n_requests`` requests with uniform random prompts drawn from
+    ``seed``."""
+    rng = np.random.default_rng(seed)
+    requests = []
+    for rid in range(n_requests):
+        if cfg.input_mode == "codebooks":
+            prompt = rng.integers(0, cfg.vocab_size,
+                                  size=(prompt_len, cfg.n_codebooks),
+                                  dtype=np.int32)
+        else:
+            prompt = rng.integers(0, cfg.vocab_size, size=prompt_len,
+                                  dtype=np.int32)
+        requests.append(Request(rid=rid, prompt=prompt,
+                                max_new_tokens=new_tokens,
+                                temperature=temperature))
+    return requests
+
+
+def generate_all(engine: ServeEngine, requests
+                 ) -> Tuple[Dict[int, list], float]:
+    """Stream every request to completion: ``({rid: tokens}, seconds)``."""
+    t0 = time.perf_counter()
+    streamed: Dict[int, list] = {}
+    for rid, token in engine.generate(requests):
+        streamed.setdefault(rid, []).append(token)
+    return streamed, time.perf_counter() - t0
 
 
 def main(argv=None):
@@ -27,30 +63,14 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     params = T.init_params(cfg, seed=0)
     engine = ServeEngine(cfg, params, n_slots=args.slots,
                          cache_len=args.cache_len)
-
-    rng = np.random.default_rng(0)
-    requests = []
-    for rid in range(args.requests):
-        if cfg.input_mode == "codebooks":
-            prompt = rng.integers(0, cfg.vocab_size,
-                                  size=(args.prompt_len, cfg.n_codebooks),
-                                  dtype=np.int32)
-        else:
-            prompt = rng.integers(0, cfg.vocab_size, size=args.prompt_len,
-                                  dtype=np.int32)
-        requests.append(Request(rid=rid, prompt=prompt,
-                                max_new_tokens=args.new_tokens,
-                                temperature=args.temperature))
-
-    t0 = time.time()
-    streamed = {}
-    for rid, token in engine.generate(requests):
-        streamed.setdefault(rid, []).append(token)
-    dt = time.time() - t0
+    requests = make_requests(cfg, args.requests, args.prompt_len,
+                             args.new_tokens, args.temperature)
+    streamed, dt = generate_all(engine, requests)
     total_new = sum(len(toks) for toks in streamed.values())
     print(f"[serve] {len(streamed)}/{args.requests} requests, "
           f"{total_new} new tokens in {dt:.2f}s "

@@ -20,6 +20,7 @@ import jax
 from repro.configs import get_config
 from repro.configs.base import ShapeCell
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw
 from repro.train.loop import train_with_restarts
 
@@ -41,6 +42,7 @@ def main(argv=None):
                     help="e.g. 1,1 (default: all local devices on data)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")

@@ -213,9 +213,12 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int):
             "tail_ssd": _stack([S.init_ssd_cache(cfg, batch, dtype)
                                 for _ in range(cfg.hybrid_tail)]),
         }
+    # one zeroed buffer per leaf: stacking per-layer zeros would hold the
+    # whole cache twice at its peak (the full-width KV cache is GBs)
     n = _n_repeats(cfg)
-    return _stack([_block_cache(cfg, batch, cache_len, dtype)
-                   for _ in range(n)])
+    one = jax.eval_shape(
+        functools.partial(_block_cache, cfg, batch, cache_len, dtype))
+    return jax.tree.map(lambda s: jnp.zeros((n,) + s.shape, s.dtype), one)
 
 
 def cache_specs(cfg: ModelConfig, batch: int, cache_len: int):

@@ -4,9 +4,9 @@ The serving engine, the paged decode gather, and the sharded SpMM all
 promise "never retraces" in comments; this module turns that into an
 assertion.  :func:`monitor` wraps a function that jit (or grad / vmap /
 scan) will trace; the wrapper bumps a named :class:`Sentinel` ONLY when
-called under an active jax trace (``jax.core.trace_state_clean()`` is
-False) — i.e. exactly once per (re)trace per call site, and never on
-eager calls or jit cache hits.  ``assert_max_traces(target, n)`` then
+called with a traced argument (any pytree leaf is a ``jax.core.Tracer``)
+— i.e. exactly once per (re)trace per call site, and never on eager calls
+or jit cache hits.  ``assert_max_traces(target, n)`` then
 raises :class:`RetraceError` when the count exceeds the budget.
 
 Wrap the function BEFORE handing it to ``jax.jit`` (the engine does this
@@ -17,7 +17,7 @@ the body was traced", so a function inlined L times per program counts L
 per trace; budget accordingly.
 
 This module is the one ``repro.obs`` member that is trace-time-safe by
-design (it only reads trace state and mutates host counters), so lint R7
+design (it only inspects argument types and mutates host counters), so lint R7
 (``obs-host-only``) exempts it.
 
 >>> import jax, jax.numpy as jnp
@@ -73,12 +73,10 @@ _REGISTRY: Dict[str, Sentinel] = {}
 _LOCK = threading.Lock()
 
 
-def _trace_active() -> bool:
-    try:
-        import jax
-        return not jax.core.trace_state_clean()
-    except ImportError:
-        return False
+def _trace_active(args, kwargs) -> bool:
+    import jax
+    return any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree.leaves((args, kwargs)))
 
 
 def monitor(fn=None, *, name: Optional[str] = None):
@@ -96,7 +94,7 @@ def monitor(fn=None, *, name: Optional[str] = None):
 
     @functools.wraps(fn)
     def wrapper(*a, **kw):
-        if _trace_active():
+        if _trace_active(a, kw):
             n = s.bump()
             _trace.event("jax.trace", fn=s.name, n=n)
         return fn(*a, **kw)

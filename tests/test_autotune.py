@@ -94,7 +94,7 @@ def test_tune_never_slower_than_default_and_persists(tmp_path):
     cache = tmp_path / "autotune.json"
     tuner = autotune.Autotuner(cache_path=str(cache))
     a = _mk(seed=2, shape=(128, 128), density=0.2)
-    choice, timings = tuner.tune(a, 64, iters=2)
+    choice, timings = tuner.tune(a, 64, iters=2, interpret=True)
     assert choice.source == "measured"
     default_label = f"{autotune.DEFAULT_VARIANT}/bn{autotune.DEFAULT_BN}"
     tuned_label = f"{choice.variant}/bn{choice.bn}"
@@ -112,6 +112,52 @@ def test_tune_never_slower_than_default_and_persists(tmp_path):
     assert hit is not None
     assert (hit.variant, hit.bn, hit.source) == (choice.variant, choice.bn,
                                                  "measured")
+
+
+def _failing_backend(monkeypatch, backend):
+    """Make every ``ops.spmm`` dispatch on ``backend`` raise."""
+    real = ops.spmm
+
+    def spmm(*a, **kw):
+        if kw.get("backend") == backend:
+            raise NotImplementedError(f"{backend} unavailable")
+        return real(*a, **kw)
+    monkeypatch.setattr(ops, "spmm", spmm)
+
+
+def test_tune_records_failed_candidates(monkeypatch):
+    from repro.obs import trace
+    _failing_backend(monkeypatch, "row_loop")
+    tuner = autotune.Autotuner()
+    a = _mk(seed=2, shape=(128, 128), density=0.2)
+    with trace.capture() as cap:
+        choice, timings = tuner.tune(a, 64, iters=1, interpret=True)
+    failed = {k for k, v in timings.items() if isinstance(v, str)}
+    assert failed and all(k.startswith("row_loop/") for k in failed)
+    assert all(timings[k] == "failed: NotImplementedError" for k in failed)
+    assert choice.source == "measured" and choice.variant != "row_loop"
+    events = {e.args["candidate"] for e in cap.events
+              if e.name == "autotune.candidate_failed"}
+    assert events == failed
+
+
+@pytest.mark.parametrize("failing", ["pallas", "compiled-on-cpu"])
+def test_tune_raises_when_default_fails(monkeypatch, failing):
+    """A sweep whose default candidate cannot run caches nothing: neither
+    when the kernel raises, nor when non-interpret Pallas is asked of a
+    CPU backend."""
+    interpret = True
+    if failing == "pallas":
+        _failing_backend(monkeypatch, "pallas")
+    elif jax.default_backend() == "cpu":
+        interpret = False
+    else:
+        pytest.skip("compiled Pallas runs on this backend")
+    tuner = autotune.Autotuner()
+    a = _mk(seed=2, shape=(128, 128), density=0.2)
+    with pytest.raises(RuntimeError, match="default candidate"):
+        tuner.tune(a, 64, iters=1, interpret=interpret)
+    assert len(tuner) == 0
 
 
 def test_corrupt_cache_tolerated(tmp_path):
@@ -137,7 +183,8 @@ def test_spmm_auto_uses_measured_cache_entry():
     a = _mk(seed=4)
     arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32)
     n = 64
-    choice, _ = autotune.get_autotuner().tune(a, n, iters=1)
+    choice, _ = autotune.get_autotuner().tune(a, n, iters=1,
+                                               interpret=True)
     backend, bn = ops.resolve_backend("auto", 512, meta, n)
     assert backend == autotune.get_variant(choice.variant).backend
     assert bn == choice.bn

@@ -195,7 +195,7 @@ def test_tune_shards_caches_measured_picks():
     autotune.set_autotuner(tuner)
     try:
         tuned = dist_spmm.tune_shards(sharr, smeta, 32, iters=1,
-                                      tuner=tuner)
+                                      interpret=True, tuner=tuner)
         for m in smeta.shard_metas:
             hit = tuner.get(autotune.fingerprint(m, 32))
             assert hit is not None and hit.source == "measured"

@@ -100,3 +100,23 @@ def test_model_flops_moe_uses_active_params():
     assert active < total * 0.45        # MoE: activates well under half
     mf = rl.model_flops_for(lite, SHAPES["train_4k"])
     assert mf == pytest.approx(6.0 * active * 4096 * 256)
+
+
+def test_import_leaves_env_and_lowers_sparse_as_xla(monkeypatch):
+    """Importing the dry-run (``launch.train`` does, for its shard report)
+    must not touch ``XLA_FLAGS``; its lowering swaps the registered
+    ``auto`` sparse backends for ``xla`` — no Pallas kernel compiles for
+    the CPU."""
+    import importlib
+
+    from repro.configs import get_config
+    from repro.launch import dryrun
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    importlib.reload(dryrun)
+    assert "XLA_FLAGS" not in os.environ
+    cfg = get_config("smat-attn-1.3b")
+    assert cfg.ffn_sparsity.backend == cfg.attn_sparsity.backend == "auto"
+    low = dryrun.xla_lowered(cfg)
+    assert low.ffn_sparsity.backend == low.attn_sparsity.backend == "xla"
+    assert dryrun.xla_lowered(get_config("h2o-danube-1.8b")) is \
+        get_config("h2o-danube-1.8b")

@@ -203,7 +203,8 @@ def test_tune_sddmm_measured_and_persisted(tmp_path):
     a = _mk(shape=(64, 64), density=0.4)
     cache = str(tmp_path / "tuned.json")
     tuner = autotune.Autotuner(cache_path=cache)
-    choice, timings = tuner.tune(a, 16, op="sddmm", iters=1)
+    choice, timings = tuner.tune(a, 16, op="sddmm", iters=1,
+                                 interpret=True)
     assert choice.variant in autotune.variant_names("sddmm")
     assert choice.source == "measured"
     assert timings

@@ -2,6 +2,9 @@
 CSR -> reorder -> BCSR -> kernels inside a model -> train -> checkpoint ->
 serve — wired together exactly as the launchers do."""
 import dataclasses
+import os
+import subprocess
+import sys
 
 import numpy as np
 import jax
@@ -116,3 +119,27 @@ def test_benchmark_modules_importable():
         assert callable(mod.run), mod_name
     assert callable(
         importlib.import_module("benchmarks.compare_sweeps").main)
+
+
+def test_compile_cache_lands_in_env_dir(tmp_path, monkeypatch):
+    """``enable_compile_cache`` (called by the entry points) leaves the
+    directory to ``JAX_COMPILATION_CACHE_DIR`` when it is set, and falls
+    back to the fixed in-checkout ``.jax_cache`` otherwise."""
+    from repro.launch import compile_cache
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert str(compile_cache.DEFAULT_DIR) == os.path.join(repo, ".jax_cache")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               PYTHONPATH=os.path.join(repo, "src"))
+    code = ("from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "import jax, jax.numpy as jnp\n"
+            "jax.jit(lambda x: x @ x)(jnp.ones((8, 8))).block_until_ready()\n")
+    p = subprocess.run([sys.executable, "-c", code], env=env, cwd=repo,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+    assert p.stdout.strip() == str(tmp_path)
+    assert any(f.name.startswith("jit_") for f in tmp_path.iterdir())
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.cache_dir() == str(compile_cache.DEFAULT_DIR)
