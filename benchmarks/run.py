@@ -63,9 +63,8 @@ def run_suite(smoke: bool, diff_all: bool, out_dir: str = ".") -> int:
     from repro.obs import trace as obs_trace
 
     rc = 0
-    # the runner is an obs consumer: every suite module runs under a span
-    # and the whole run exports a Perfetto trace next to the BENCH_*.json
-    # artifacts (same glob, so CI uploads it for free)
+    # the runner is an obs consumer: every suite module runs under a span,
+    # and the run ends with the span tree of where its wall clock went
     with obs_trace.capture() as cap:
         for mod_name, baseline_name in SUITE:
             mod = importlib.import_module(f"benchmarks.{mod_name}")
@@ -82,10 +81,6 @@ def run_suite(smoke: bool, diff_all: bool, out_dir: str = ".") -> int:
                 with open(baseline_path) as f:
                     baseline = json.load(f)
                 rc |= mod.diff(result, baseline)
-    perfetto_path = os.path.join(out_dir, "BENCH_trace_perfetto.json")
-    obs_export.write_perfetto(cap.events, perfetto_path)
-    print(f"wrote {perfetto_path} ({len(cap.events)} events)",
-          file=sys.stderr)
     print(obs_export.summary_tree(cap.events), file=sys.stderr)
     return rc
 

@@ -43,6 +43,7 @@ from repro.core import native
 from repro.core.reorder import identity as _identity_rows
 from repro.core.reorder import rcm as _rcm_rows
 from repro.core.reorder import shard_balance as _shard_balance_brows
+from repro.obs import trace as obs_trace
 
 try:  # numpy >= 2.0
     _popcount = np.bitwise_count
@@ -511,16 +512,23 @@ def permute_bcsr(a: bcsr_lib.BCSR, scheme: str = "jaccard", *,
     if granularity != "element":
         raise ValueError(f"granularity must be 'element' or 'block_row', "
                          f"got {granularity!r}")
-    csr = a.to_scipy()
-    perm = SCHEMES[scheme](csr, block=a.block, tau=tau,
-                           max_candidates=max_candidates, n_shards=n_shards)
+    with obs_trace.span("prepare.reorder.to_csr"):
+        csr = a.to_scipy()
+    with obs_trace.span("prepare.reorder.cluster"):
+        perm = SCHEMES[scheme](csr, block=a.block, tau=tau,
+                               max_candidates=max_candidates,
+                               n_shards=n_shards)
     if isinstance(perm, tuple):
         raise ValueError(
             f"scheme {scheme!r} returns a column permutation too; "
             "prepare_sparse only supports row permutations (the paper "
             "rejects column permutation — it would permute B)")
     perm = np.asarray(perm, dtype=np.int64)
-    return bcsr_lib.from_scipy(csr[perm].tocsr(), a.block), perm
+    with obs_trace.span("prepare.reorder.reblock"):
+        p = csr[perm].tocsr()
+        a_p = bcsr_lib._from_csr(p.indptr, p.indices, p.data, p.shape,
+                                 a.block)
+    return a_p, perm
 
 
 def invert_perm(perm: np.ndarray) -> np.ndarray:

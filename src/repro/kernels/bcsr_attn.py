@@ -238,5 +238,6 @@ def bcsr_attn_fused(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((G, n_block_rows * h, vpad),
                                        out_dtype),
         interpret=interpret,
+        name="smat_attn_fused",
     )(flat_idx, flat_col, qp, kp, vp, em_ext)
     return out[:, :Lq, :dv]

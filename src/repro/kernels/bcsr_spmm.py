@@ -98,6 +98,7 @@ def bcsr_spmm_nnz_stream(vals: jnp.ndarray, row_ids: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_block_rows * h, N), out_dtype),
         interpret=interpret,
+        name="smat_spmm_nnz_stream",
     )(row_ids, col_ids, vals, b)
 
 
@@ -163,6 +164,7 @@ def bcsr_spmm_row_loop(vals: jnp.ndarray, flat_idx: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_block_rows * h, N), out_dtype),
         interpret=interpret,
+        name="smat_spmm_row_loop",
     )(flat_idx, flat_col, row_len, vals, b)
 
 
@@ -220,6 +222,7 @@ def bcsr_sddmm(dc: jnp.ndarray, b: jnp.ndarray, row_ids: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nnzb, h, w), out_dtype),
         interpret=interpret,
+        name="smat_sddmm_nnz_stream",
     )(row_ids, col_ids, dc, b)
 
 
@@ -290,5 +293,6 @@ def bcsr_sddmm_row_loop(dc: jnp.ndarray, b: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nnzb + 1, h, w), out_dtype),
         interpret=interpret,
+        name="smat_sddmm_row_loop",
     )(flat_idx, flat_col, dc, b)
     return out[:nnzb]

@@ -126,19 +126,17 @@ def _prepare_sparse_host(a: bcsr_lib.BCSR, *, reorder: str,
     from repro.core import permute as permute_lib  # local: import cycle
     from repro.obs import metrics as obs_metrics
     from repro.obs import trace as obs_trace
-    nnzb_in = a.nnzb
     with obs_trace.span("prepare.reorder", scheme=reorder,
-                        granularity=reorder_granularity):
+                        granularity=reorder_granularity), \
+            obs_metrics.timer("prepare.seconds", stage="reorder"):
         a, row_perm_np = permute_lib.permute_bcsr(
             a, reorder, tau=tau, max_candidates=max_candidates,
             n_shards=n_shards, granularity=reorder_granularity)
-    if nnzb_in:
-        obs_metrics.gauge("prepare.nnzb_reduction_pct", scheme=reorder).set(
-            round(100.0 * (nnzb_in - a.nnzb) / nnzb_in, 2))
     # padding entries are tagged explicitly by ensure_nonempty_rows (before
     # its lexsort), so genuinely-zero original blocks — e.g. from
     # random_bcsr(fill_density<1) — keep real_mask=True and stay trainable.
-    with obs_trace.span("prepare.meta"):
+    with obs_trace.span("prepare.meta"), \
+            obs_metrics.timer("prepare.seconds", stage="meta"):
         a_p, real_mask = a.ensure_nonempty_rows(return_mask=True)
 
         # ---- transpose structure (entries of A^T in A^T row-major order) --
@@ -163,6 +161,13 @@ def _prepare_sparse_host(a: bcsr_lib.BCSR, *, reorder: str,
                 t_perm[order_t], t_row_ids[order_t], t_col_ids[order_t])
 
         inv_perm_np = permute_lib.invert_perm(row_perm_np)
+        max_bpr, pad_pct, cv_pct = a_p.dispatch_stats()
+        meta = SparseMeta(shape=a_p.shape, block=a_p.block,
+                          n_block_rows=a_p.n_block_rows,
+                          n_block_cols=a_p.n_block_cols,
+                          nnzb=a_p.nnzb, nnzb_t=int(t_row_ids.shape[0]),
+                          max_bpr=max_bpr, padding_ratio_pct=pad_pct,
+                          bpr_cv_pct=cv_pct, reorder=reorder)
     host = {
         "vals": a_p.vals,
         "row_ids": a_p.row_ids,
@@ -174,17 +179,9 @@ def _prepare_sparse_host(a: bcsr_lib.BCSR, *, reorder: str,
         "row_perm": row_perm_np,
         "inv_perm": inv_perm_np,
     }
-    max_bpr, pad_pct, cv_pct = a_p.dispatch_stats()
-    meta = SparseMeta(shape=a_p.shape, block=a_p.block,
-                      n_block_rows=a_p.n_block_rows,
-                      n_block_cols=a_p.n_block_cols,
-                      nnzb=a_p.nnzb, nnzb_t=int(t_row_ids.shape[0]),
-                      max_bpr=max_bpr, padding_ratio_pct=pad_pct,
-                      bpr_cv_pct=cv_pct, reorder=reorder)
     obs_trace.event("prepare.done", shape=meta.shape, block=meta.block,
                     nnzb=meta.nnzb, nnzb_t=meta.nnzb_t,
                     max_bpr=meta.max_bpr, reorder=reorder)
-    obs_metrics.gauge("prepare.nnzb", scheme=reorder).set(meta.nnzb)
     return host, meta
 
 
@@ -194,7 +191,9 @@ def prepare_sparse(a: bcsr_lib.BCSR, dtype=jnp.bfloat16, *,
                    tau: float = 0.7, max_candidates: Optional[int] = None,
                    n_shards: int = 8
                    ) -> Tuple[SparseArrays, SparseMeta]:
-    """Host BCSR -> kernel-ready device arrays + static meta.
+    """Host BCSR -> kernel-ready device arrays + static meta.  Returns
+    once the arrays are on the device; each stage sets its gauge
+    ``prepare.seconds{stage=reorder|meta|to_device}``.
 
     ``reorder`` applies a block-densifying row permutation first (any
     scheme in ``core.permute.SCHEMES`` that yields a pure row permutation:
@@ -225,20 +224,27 @@ def prepare_sparse(a: bcsr_lib.BCSR, dtype=jnp.bfloat16, *,
     >>> (meta.nnzb, meta.max_bpr, meta.row_loop_sched_len)
     (4, 1, 4)
     """
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as obs_trace
     host, meta = _prepare_sparse_host(
         a, reorder=reorder, reorder_granularity=reorder_granularity,
         tau=tau, max_candidates=max_candidates, n_shards=n_shards)
-    arrays = SparseArrays(
-        vals=jnp.asarray(host["vals"], dtype=dtype),
-        row_ids=jnp.asarray(host["row_ids"], dtype=jnp.int32),
-        col_ids=jnp.asarray(host["col_ids"], dtype=jnp.int32),
-        real_mask=jnp.asarray(host["real_mask"]),
-        t_perm=jnp.asarray(host["t_perm"], dtype=jnp.int32),
-        t_row_ids=jnp.asarray(host["t_row_ids"], dtype=jnp.int32),
-        t_col_ids=jnp.asarray(host["t_col_ids"], dtype=jnp.int32),
-        row_perm=jnp.asarray(host["row_perm"], dtype=jnp.int32),
-        inv_perm=jnp.asarray(host["inv_perm"], dtype=jnp.int32),
-    )
+    with obs_trace.span("prepare.to_device"), \
+            obs_metrics.timer("prepare.seconds", stage="to_device"):
+        arrays = SparseArrays(
+            vals=jnp.asarray(host["vals"], dtype=dtype),
+            row_ids=jnp.asarray(host["row_ids"], dtype=jnp.int32),
+            col_ids=jnp.asarray(host["col_ids"], dtype=jnp.int32),
+            real_mask=jnp.asarray(host["real_mask"]),
+            t_perm=jnp.asarray(host["t_perm"], dtype=jnp.int32),
+            t_row_ids=jnp.asarray(host["t_row_ids"], dtype=jnp.int32),
+            t_col_ids=jnp.asarray(host["t_col_ids"], dtype=jnp.int32),
+            row_perm=jnp.asarray(host["row_perm"], dtype=jnp.int32),
+            inv_perm=jnp.asarray(host["inv_perm"], dtype=jnp.int32),
+        )
+        # the stage covers the transfer itself; the caller waits for the
+        # arrays anyway
+        jax.block_until_ready(arrays)
     return arrays, meta
 
 
@@ -355,38 +361,44 @@ def _fwd_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
     M, K = meta.shape
     out_dtype = jnp.dtype(cfg.out_dtype) if cfg.out_dtype else b.dtype
     bn = _clamp_bn(cfg.bn, b.shape[1])
-    b_p, N = _pad_b(b, w, bn)
+    with jax.named_scope("smat.pad"):
+        b_p, N = _pad_b(b, w, bn)
     bn = min(bn, b_p.shape[1])
-    if cfg.backend == "pallas":
-        out = pk.bcsr_spmm_nnz_stream(
-            arrays.vals, arrays.row_ids, arrays.col_ids, b_p,
-            meta.n_block_rows, bn=bn, out_dtype=out_dtype,
-            interpret=cfg.interpret)
-    elif cfg.backend == "row_loop":
-        if meta.max_bpr <= 0:
-            raise ValueError(
-                "backend='row_loop' needs meta.max_bpr > 0 (metas built by "
-                "prepare_sparse have it; hand-built specs metas do not)")
-        flat_idx, flat_col, row_len = _row_loop_schedule(
-            arrays.row_ids, arrays.col_ids, meta.n_block_rows, meta.max_bpr)
-        out = pk.bcsr_spmm_row_loop(
-            arrays.vals, flat_idx, flat_col, row_len, b_p,
-            meta.n_block_rows, bn=bn, out_dtype=out_dtype,
-            interpret=cfg.interpret)
-    elif cfg.backend == "xla":
-        out = ref.bcsr_spmm_ref(arrays.vals, arrays.row_ids, arrays.col_ids,
-                                b_p, meta.n_block_rows, out_dtype=out_dtype)
-    elif cfg.backend == "dense":
-        dense = materialize_dense(arrays, meta)
-        out = ref.spmm_dense_ref(dense, b_p[: dense.shape[1]],
-                                 out_dtype=out_dtype)
-    else:
-        raise ValueError(f"unknown backend {cfg.backend!r}")
-    out = out[:M, :N]
-    if meta.reorder != "identity" and arrays.inv_perm is not None:
-        # kernel computed C' = A' B in permuted row order; hand back
-        # C = P^T C' so the permutation never leaks to callers
-        out = jnp.take(out, arrays.inv_perm, axis=0)
+    with jax.named_scope(f"smat.kernel.{cfg.backend}"):
+        if cfg.backend == "pallas":
+            out = pk.bcsr_spmm_nnz_stream(
+                arrays.vals, arrays.row_ids, arrays.col_ids, b_p,
+                meta.n_block_rows, bn=bn, out_dtype=out_dtype,
+                interpret=cfg.interpret)
+        elif cfg.backend == "row_loop":
+            if meta.max_bpr <= 0:
+                raise ValueError(
+                    "backend='row_loop' needs meta.max_bpr > 0 (metas built "
+                    "by prepare_sparse have it; hand-built specs metas do "
+                    "not)")
+            flat_idx, flat_col, row_len = _row_loop_schedule(
+                arrays.row_ids, arrays.col_ids, meta.n_block_rows,
+                meta.max_bpr)
+            out = pk.bcsr_spmm_row_loop(
+                arrays.vals, flat_idx, flat_col, row_len, b_p,
+                meta.n_block_rows, bn=bn, out_dtype=out_dtype,
+                interpret=cfg.interpret)
+        elif cfg.backend == "xla":
+            out = ref.bcsr_spmm_ref(arrays.vals, arrays.row_ids,
+                                    arrays.col_ids, b_p, meta.n_block_rows,
+                                    out_dtype=out_dtype)
+        elif cfg.backend == "dense":
+            dense = materialize_dense(arrays, meta)
+            out = ref.spmm_dense_ref(dense, b_p[: dense.shape[1]],
+                                     out_dtype=out_dtype)
+        else:
+            raise ValueError(f"unknown backend {cfg.backend!r}")
+    with jax.named_scope("smat.epilogue"):
+        out = out[:M, :N]
+        if meta.reorder != "identity" and arrays.inv_perm is not None:
+            # kernel computed C' = A' B in permuted row order; hand back
+            # C = P^T C' so the permutation never leaks to callers
+            out = jnp.take(out, arrays.inv_perm, axis=0)
     return out
 
 
@@ -395,24 +407,30 @@ def _dx_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
     """dB = A^T @ dC via the transpose structure."""
     h, w = meta.block
     M, K = meta.shape
-    sentinel = jnp.zeros((1,) + tuple(arrays.vals.shape[1:]),
-                         dtype=arrays.vals.dtype)
-    vals_ext = jnp.concatenate([arrays.vals, sentinel], axis=0)
-    t_vals = jnp.transpose(vals_ext[arrays.t_perm], (0, 2, 1))  # [nnzb_t,w,h]
+    with jax.named_scope("smat.permute"):
+        sentinel = jnp.zeros((1,) + tuple(arrays.vals.shape[1:]),
+                             dtype=arrays.vals.dtype)
+        vals_ext = jnp.concatenate([arrays.vals, sentinel], axis=0)
+        t_vals = jnp.transpose(vals_ext[arrays.t_perm], (0, 2, 1))
     bn = _clamp_bn(cfg.bn, g.shape[1])
-    g_p, N = _pad_b(g, h, bn)
+    with jax.named_scope("smat.pad"):
+        g_p, N = _pad_b(g, h, bn)
     bn = min(bn, g_p.shape[1])
     # row_loop is a forward-schedule choice; the backward always streams the
     # transpose structure (whose row skew differs from A's).
     if cfg.backend in ("pallas", "row_loop"):
-        out = pk.bcsr_spmm_nnz_stream(
-            t_vals, arrays.t_row_ids, arrays.t_col_ids, g_p,
-            meta.n_block_cols, bn=bn, out_dtype=g.dtype,
-            interpret=cfg.interpret)
+        with jax.named_scope("smat.kernel.pallas"):
+            out = pk.bcsr_spmm_nnz_stream(
+                t_vals, arrays.t_row_ids, arrays.t_col_ids, g_p,
+                meta.n_block_cols, bn=bn, out_dtype=g.dtype,
+                interpret=cfg.interpret)
     else:
-        out = ref.bcsr_spmm_ref(t_vals, arrays.t_row_ids, arrays.t_col_ids,
-                                g_p, meta.n_block_cols, out_dtype=g.dtype)
-    return out[:K, :N]
+        with jax.named_scope("smat.kernel.xla"):
+            out = ref.bcsr_spmm_ref(t_vals, arrays.t_row_ids,
+                                    arrays.t_col_ids, g_p, meta.n_block_cols,
+                                    out_dtype=g.dtype)
+    with jax.named_scope("smat.epilogue"):
+        return out[:K, :N]
 
 
 def _sddmm_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
@@ -428,40 +446,47 @@ def _sddmm_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
     zeroed — they are structural, not values."""
     h, w = meta.block
     if meta.reorder != "identity" and arrays.row_perm is not None:
-        x = jnp.take(x, arrays.row_perm, axis=0)
+        with jax.named_scope("smat.permute"):
+            x = jnp.take(x, arrays.row_perm, axis=0)
     out_dtype = jnp.dtype(cfg.out_dtype) if cfg.out_dtype else x.dtype
     bn = _clamp_bn(cfg.bn, max(x.shape[1], y.shape[1]))
-    x_p, _ = _pad_b(x, h, bn)
-    y_p, _ = _pad_b(y, w, bn)
-    n_pad = max(x_p.shape[1], y_p.shape[1])
-    x_p = jnp.pad(x_p, ((0, 0), (0, n_pad - x_p.shape[1])))
-    y_p = jnp.pad(y_p, ((0, 0), (0, n_pad - y_p.shape[1])))
+    with jax.named_scope("smat.pad"):
+        x_p, _ = _pad_b(x, h, bn)
+        y_p, _ = _pad_b(y, w, bn)
+        n_pad = max(x_p.shape[1], y_p.shape[1])
+        x_p = jnp.pad(x_p, ((0, 0), (0, n_pad - x_p.shape[1])))
+        y_p = jnp.pad(y_p, ((0, 0), (0, n_pad - y_p.shape[1])))
     bn = min(bn, n_pad)
-    if cfg.backend == "pallas":
-        vals = pk.bcsr_sddmm(x_p, y_p, arrays.row_ids, arrays.col_ids,
-                             h, w, bn=bn, out_dtype=out_dtype,
-                             interpret=cfg.interpret)
-    elif cfg.backend == "row_loop":
-        if meta.max_bpr <= 0:
-            raise ValueError(
-                "backend='row_loop' needs meta.max_bpr > 0 (metas built by "
-                "prepare_sparse have it; hand-built specs metas do not)")
-        flat_idx, flat_col = _sddmm_row_loop_schedule(
-            arrays.row_ids, arrays.col_ids, meta.n_block_rows, meta.max_bpr)
-        vals = pk.bcsr_sddmm_row_loop(
-            x_p, y_p, flat_idx, flat_col, meta.n_block_rows, meta.nnzb,
-            h, w, bn=bn, out_dtype=out_dtype, interpret=cfg.interpret)
-    elif cfg.backend == "xla":
-        vals = ref.bcsr_sddmm_ref(x_p, y_p, arrays.row_ids, arrays.col_ids,
-                                  h, w, out_dtype=out_dtype)
-    elif cfg.backend == "dense":
-        vals = ref.bcsr_sddmm_dense_ref(x_p, y_p, arrays.row_ids,
-                                        arrays.col_ids, h, w,
-                                        out_dtype=out_dtype)
-    else:
-        raise ValueError(f"unknown backend {cfg.backend!r}")
+    with jax.named_scope(f"smat.kernel.{cfg.backend}"):
+        if cfg.backend == "pallas":
+            vals = pk.bcsr_sddmm(x_p, y_p, arrays.row_ids, arrays.col_ids,
+                                 h, w, bn=bn, out_dtype=out_dtype,
+                                 interpret=cfg.interpret)
+        elif cfg.backend == "row_loop":
+            if meta.max_bpr <= 0:
+                raise ValueError(
+                    "backend='row_loop' needs meta.max_bpr > 0 (metas built "
+                    "by prepare_sparse have it; hand-built specs metas do "
+                    "not)")
+            flat_idx, flat_col = _sddmm_row_loop_schedule(
+                arrays.row_ids, arrays.col_ids, meta.n_block_rows,
+                meta.max_bpr)
+            vals = pk.bcsr_sddmm_row_loop(
+                x_p, y_p, flat_idx, flat_col, meta.n_block_rows, meta.nnzb,
+                h, w, bn=bn, out_dtype=out_dtype, interpret=cfg.interpret)
+        elif cfg.backend == "xla":
+            vals = ref.bcsr_sddmm_ref(x_p, y_p, arrays.row_ids,
+                                      arrays.col_ids, h, w,
+                                      out_dtype=out_dtype)
+        elif cfg.backend == "dense":
+            vals = ref.bcsr_sddmm_dense_ref(x_p, y_p, arrays.row_ids,
+                                            arrays.col_ids, h, w,
+                                            out_dtype=out_dtype)
+        else:
+            raise ValueError(f"unknown backend {cfg.backend!r}")
     # padding entries are structural zeros — never values, never gradients
-    return vals * arrays.real_mask[:, None, None].astype(vals.dtype)
+    with jax.named_scope("smat.epilogue"):
+        return vals * arrays.real_mask[:, None, None].astype(vals.dtype)
 
 
 def materialize_dense(arrays: SparseArrays, meta: SparseMeta) -> jnp.ndarray:
@@ -495,7 +520,8 @@ def _spmm_bwd(cfg, meta, res, g):
         # cotangent arrives in ORIGINAL row order; the stored structure is
         # A' = P A, so dB = A'^T (P dC) needs the permuted cotangent
         # g' = P g (the SDDMM op permutes its X operand itself)
-        g2 = jnp.take(g2, arrays.row_perm, axis=0)
+        with jax.named_scope("smat.permute"):
+            g2 = jnp.take(g2, arrays.row_perm, axis=0)
     db = _dx_impl(cfg, meta, arrays, g2)[: b.shape[0], : b.shape[1]]
     # dvals through the SDDMM op — SpMM and SDDMM are mutual duals, so
     # higher-order AD recurses between the two custom VJPs
@@ -534,7 +560,8 @@ def _sddmm_bwd(cfg, meta, res, g):
     garr = SparseArrays(gm.astype(y.dtype), *rest)
     xp = x
     if meta.reorder != "identity" and garr.row_perm is not None:
-        xp = jnp.take(x, garr.row_perm, axis=0)
+        with jax.named_scope("smat.permute"):
+            xp = jnp.take(x, garr.row_perm, axis=0)
     dy = _dx_impl(cfg_b, meta, garr, xp)[: y.shape[0], : y.shape[1]]
     zeros_rest = jax.tree.map(
         lambda t: np.zeros(t.shape, jax.dtypes.float0), rest)

@@ -8,13 +8,17 @@ Host-side and deterministic-friendly by construction:
 * ``repro.obs.metrics`` — process-wide counters/gauges/histograms with
   labeled series, ``snapshot()``/``reset()``, and the shared benchmark
   ``timeit`` loop;
-* ``repro.obs.export``  — JSONL / Perfetto ``trace_event`` / summary-tree
-  views with deterministic payloads split from report-only wall clock;
+* ``repro.obs.export``  — JSONL / summary-tree views with deterministic
+  payloads split from report-only wall clock;
 * ``repro.obs.jaxmon``  — retrace sentinel (``monitor`` +
-  ``assert_max_traces``) turning "never retraces" comments into CI gates.
+  ``assert_max_traces``) turning "never retraces" comments into CI gates,
+  and the compile counters (``jax.compile.seconds{phase=...}``).
 
-The obs core never imports jax (``jaxmon``/``timeit`` import it lazily),
-so pure-host modules like ``serve.scheduler`` can emit events freely.
+While tracing is on, spans are mirrored into ``jax.profiler``
+annotations, so a profiler trace shows them on the device ops' clock.
+The obs core (``trace``, ``metrics``, ``export``) never imports jax
+(``timeit`` imports it lazily, the span mirror uses it only once
+imported); ``jaxmon`` imports it to register its compile listener.
 Lint R7 (``analysis.lint_rules``) keeps every ``repro.obs`` call out of
 custom_vjp/Pallas-traced code — ``jaxmon`` excepted, trace-aware by
 design.

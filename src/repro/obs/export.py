@@ -1,13 +1,10 @@
 """Exporters over the ``repro.obs.trace`` event stream.
 
-Three views of the same records:
+Two views of the same records:
 
 * **JSONL** — one ``Event.to_dict()`` per line (the on-disk format the
   ``REPRO_TRACE=<path>`` sink streams); :func:`read_jsonl` round-trips
   it back into :class:`~repro.obs.trace.Event` objects bit-for-bit.
-* **Perfetto / Chrome** — ``trace_event`` JSON (``{"traceEvents": [...]}``
-  with ``ph`` B/E/i records) loadable in ``ui.perfetto.dev`` or
-  ``chrome://tracing``.
 * **Summary tree** — plain-text aggregation by span path (call counts,
   total wall time, instant-event tallies): the
   ``python -m repro.obs.summary`` CLI.
@@ -27,9 +24,10 @@ view into one pin-able string.
 [{'kind': 'B', 'name': 'phase', 'args': {'k': 1}}, \
 {'kind': 'I', 'name': 'item', 'args': {'i': 7}}, \
 {'kind': 'E', 'name': 'phase', 'args': None}]
->>> pf = to_perfetto(cap.events)
->>> [e["ph"] for e in pf["traceEvents"]]
-['B', 'i', 'E']
+
+A timeline of the spans beside the device's operations comes from the
+profiler instead: while tracing is on, every span is also a
+``jax.profiler.TraceAnnotation`` (``repro.obs.trace``).
 """
 from __future__ import annotations
 
@@ -95,31 +93,6 @@ def read_jsonl(path: str) -> List[Event]:
                              d.get("span"), d.get("parent"), d.get("args"),
                              d.get("ts_us"), d.get("dur_us")))
     return out
-
-
-# ------------------------------------------------------------- Perfetto
-def to_perfetto(events: Iterable[Event], pid: int = 1,
-                tid: int = 1) -> dict:
-    """Chrome/Perfetto ``trace_event`` JSON.  ``B``/``E`` map directly;
-    instant events become ``ph="i"`` thread-scoped marks.  Events from a
-    deterministic-only source (no ``ts_us``) fall back to their ``seq``
-    as a synthetic timeline."""
-    recs = []
-    for e in events:
-        ts = e.ts_us if e.ts_us is not None else float(e.seq)
-        rec = {"name": e.name, "ph": e.kind if e.kind in ("B", "E") else "i",
-               "ts": ts, "pid": pid, "tid": tid}
-        if rec["ph"] == "i":
-            rec["s"] = "t"
-        if e.args:
-            rec["args"] = e.args
-        recs.append(rec)
-    return {"traceEvents": recs, "displayTimeUnit": "ms"}
-
-
-def write_perfetto(events: Iterable[Event], path: str) -> None:
-    with open(path, "w") as f:
-        json.dump(to_perfetto(events), f, indent=1, sort_keys=True)
 
 
 # --------------------------------------------------------- summary tree

@@ -1,4 +1,4 @@
-"""Retrace sentinel: make static-shape promises CI-enforced facts.
+"""Retrace sentinel and compile counters: what jit traced and compiled.
 
 The serving engine, the paged decode gather, and the sharded SpMM all
 promise "never retraces" in comments; this module turns that into an
@@ -16,9 +16,20 @@ traced code (``models.layers._paged_decode``,
 the body was traced", so a function inlined L times per program counts L
 per trace; budget accordingly.
 
+Compile counters: on its first import this module registers
+``jax.monitoring`` listeners, which add the seconds of each compile phase
+of every jitted program to the gauge ``jax.compile.seconds{phase=...}``
+(``trace``: tracing to a jaxpr; ``lower``: the jaxpr to an MLIR module;
+``compile``: the backend compile; ``cache_load``: a load from the
+persistent compilation cache, which JAX times inside its backend-compile
+span, so it is taken out of ``compile``), and count ``jax.compiles``
+(backend compiles) and ``jax.cache_hits`` (loads).  A jit traced inside
+another's trace is counted once, inside its parent.  So the registry
+says which step recompiled and what set-up spent compiling.
+
 This module is the one ``repro.obs`` member that is trace-time-safe by
 design (it only inspects argument types and mutates host counters), so lint R7
-(``obs-host-only``) exempts it.
+(``obs-host-only``) exempts it.  It imports jax, to register the listener.
 
 >>> import jax, jax.numpy as jnp
 >>> @monitor(name="doc.f")
@@ -41,6 +52,9 @@ import functools
 import threading
 from typing import Dict, Optional
 
+import jax.monitoring
+
+from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 
 
@@ -150,3 +164,61 @@ def sentinels() -> Dict[str, int]:
     """Snapshot ``{name: trace_count}`` of every registered sentinel."""
     with _LOCK:
         return {name: s.count for name, s in sorted(_REGISTRY.items())}
+
+
+# ---------------------------------------------------------- compile counters
+_SPAN_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_STACK_CAP = 1024              # spans kept per phase and thread for nesting
+_compile_tls = threading.local()
+_COMPILE_LOCK = threading.Lock()
+
+
+def _add_seconds(phase: str, secs: float) -> None:
+    with _COMPILE_LOCK:                    # threads may compile at once
+        g = _metrics.gauge("jax.compile.seconds", phase=phase)
+        g.set(g.value + secs)
+
+
+def _on_span(event: str, start: float, end: float, **_) -> None:
+    """One timed compile phase: nested spans of the same phase (a jit
+    traced inside another's trace) are reported first and counted inside
+    their parent's, so each second is counted once."""
+    phase = _SPAN_PHASES.get(event)
+    if phase is None:
+        return
+    stacks = getattr(_compile_tls, "stacks", None)
+    if stacks is None:
+        stacks = _compile_tls.stacks = {}
+    stack = stacks.setdefault(phase, [])
+    inner = 0.0
+    while stack and stack[-1][0] >= start:
+        s, e = stack.pop()
+        inner += e - s
+    stack.append((start, end))
+    del stack[:-_STACK_CAP]
+    secs = (end - start) - inner
+    if phase == "compile":
+        loaded = getattr(_compile_tls, "loaded", None)
+        _compile_tls.loaded = None
+        if loaded is None:
+            _metrics.counter("jax.compiles").inc()
+        else:
+            secs -= loaded
+    _add_seconds(phase, secs)
+
+
+def _on_duration(event: str, duration_secs: float, **_) -> None:
+    if event != _CACHE_LOAD:
+        return
+    _compile_tls.loaded = duration_secs   # inside the compile span now
+    _metrics.counter("jax.cache_hits").inc()
+    _add_seconds("cache_load", duration_secs)
+
+
+jax.monitoring.register_event_time_span_listener(_on_span)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)

@@ -26,6 +26,7 @@ True
 """
 from __future__ import annotations
 
+import contextlib
 import statistics
 import threading
 import time
@@ -165,6 +166,23 @@ def histogram(name: str, bounds: Optional[Tuple[float, ...]] = None,
 
 def snapshot() -> dict:
     return _REGISTRY.snapshot()
+
+
+@contextlib.contextmanager
+def timer(name: str, **labels):
+    """Set the gauge ``name{labels}`` to the wall seconds the ``with``
+    block took.  Always on, like every registry update: the preparation
+    stages time themselves with it (``prepare.seconds{stage=...}``).
+
+    >>> with timer("demo.seconds", stage="a"):
+    ...     pass
+    >>> snapshot()["gauges"]["demo.seconds{stage=a}"] >= 0.0
+    True
+    """
+    g = gauge(name, **labels)
+    t0 = time.perf_counter()
+    yield g
+    g.set(time.perf_counter() - t0)
 
 
 def reset() -> None:

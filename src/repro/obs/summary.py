@@ -2,7 +2,6 @@
 
     REPRO_TRACE=trace.jsonl python examples/quickstart.py
     python -m repro.obs.summary trace.jsonl
-    python -m repro.obs.summary trace.jsonl --perfetto trace_perfetto.json
 """
 from __future__ import annotations
 
@@ -17,14 +16,9 @@ def main(argv=None) -> int:
         prog="python -m repro.obs.summary", description=__doc__)
     ap.add_argument("trace", help="JSONL trace (REPRO_TRACE sink or "
                                   "export.to_jsonl output)")
-    ap.add_argument("--perfetto", default=None, metavar="OUT.json",
-                    help="also write Chrome/Perfetto trace_event JSON")
     args = ap.parse_args(argv)
     events = export.read_jsonl(args.trace)
     print(export.summary_tree(events))
-    if args.perfetto:
-        export.write_perfetto(events, args.perfetto)
-        print(f"wrote {args.perfetto}", file=sys.stderr)
     return 0
 
 

@@ -23,6 +23,12 @@ Span names are dot-scoped ``<layer>.<what>`` (``prepare.reorder``,
 ``autotune.tune``, ``serve.step``, ``bench.serving`` — see
 docs/ARCHITECTURE.md "Observability").
 
+While tracing is enabled, every span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+(``jax.profiler.trace``) shows the span on its host plane, on the clock
+of the device's operations.  jax is taken from ``sys.modules`` only when
+something else has imported it: this module never imports jax.
+
 >>> with capture() as cap:
 ...     with span("outer", n=2):
 ...         _ = event("tick", i=0)
@@ -37,6 +43,7 @@ from __future__ import annotations
 import functools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -199,14 +206,26 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _annotation(name: str):
+    """An open ``jax.profiler.TraceAnnotation`` named ``name``, or None
+    when jax has not been imported."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
-    __slots__ = ("name", "args", "_id", "_t0")
+    __slots__ = ("name", "args", "_id", "_t0", "_ann")
 
     def __init__(self, name, args):
         self.name = name
         self.args = args
         self._id = None
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
         st = _state
@@ -220,6 +239,7 @@ class _Span:
                      _now_us(st))
         self._id = ev.seq
         stack.append(ev.seq)
+        self._ann = _annotation(self.name)
         self._t0 = time.perf_counter()
         return self
 
@@ -227,6 +247,9 @@ class _Span:
         if self._id is None:
             return False
         dur = round((time.perf_counter() - self._t0) * 1e6, 3)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         stack = _stack()
         if stack and stack[-1] == self._id:
             stack.pop()

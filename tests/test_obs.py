@@ -1,16 +1,22 @@
-"""PR 10 observability pins: deterministic event payloads (bitwise-stable
-across identical runs), exporter round-trips (JSONL, Perfetto, summary
-tree), metrics snapshot/reset semantics, the retrace sentinel
-(positive AND negative), the CI retrace gates for the three monitored
-entry points (``serve.masked_step``, ``models.paged_decode``,
-``launch.spmm_sharded``), and the zero-cost contract when tracing is
-disabled."""
+"""Observability pins: deterministic event payloads (bitwise-stable
+across identical runs), exporter round-trips (JSONL, summary tree),
+metrics snapshot/reset semantics, the retrace sentinel (positive AND
+negative), the CI retrace gates for the three monitored entry points
+(``serve.masked_step``, ``models.paged_decode``,
+``launch.spmm_sharded``), the zero-cost contract when tracing is
+disabled, the spans' mirror into the profiler's trace, the preparation
+stage timers, the compile counters, and the ``smat.*`` device scopes of
+the library op."""
 import dataclasses
+import glob
 import json
 import os
+import re
+import time
 
 import numpy as np
 import pytest
+import scipy.sparse as sp
 
 import jax
 import jax.numpy as jnp
@@ -104,24 +110,6 @@ def test_jsonl_round_trip(tmp_path):
     with open(path) as f:
         lines = [json.loads(ln) for ln in f]
     assert len(lines) == len(cap.events)
-
-
-def test_perfetto_export_is_valid(tmp_path):
-    with trace.capture() as cap:
-        with trace.span("a"):
-            trace.event("i1")
-        with trace.span("b"):
-            pass
-    doc = export.to_perfetto(cap.events)
-    assert set(doc) == {"traceEvents", "displayTimeUnit"}
-    phases = [te["ph"] for te in doc["traceEvents"]]
-    assert phases.count("B") == phases.count("E") == 2
-    assert phases.count("i") == 1
-    for te in doc["traceEvents"]:
-        assert {"name", "ph", "ts", "pid", "tid"} <= set(te)
-    out = os.path.join(tmp_path, "p.json")
-    export.write_perfetto(cap.events, out)
-    assert json.load(open(out)) == doc
 
 
 def test_summary_tree_renders_span_hierarchy():
@@ -278,3 +266,110 @@ def test_capture_works_even_when_disabled():
         assert not trace.enabled()          # restored to disabled
     finally:
         trace.configure(os.environ.get("REPRO_TRACE"))
+
+
+# ------------------------------------------------- profiler mirror, stages
+def _prepare_matrix():
+    """A host CSR whose preparation takes tens of milliseconds, so the
+    stage timers dominate the few calls between them."""
+    rng = np.random.default_rng(3)
+    dense = (rng.random((512, 512)) < 0.02) * rng.standard_normal((512, 512))
+    return sp.csr_matrix(dense.astype(np.float32))
+
+
+def _host_span_names(tmp_path, fn) -> set:
+    """Names of the host plane's events in a CPU profiler trace of
+    ``fn()``."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    path, = glob.glob(os.path.join(tmp_path, "plugins/profile/*/*.xplane.pb"))
+    data = ProfileData.from_file(path)
+    return {ev.name for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events}
+
+
+def _prepare(csr):
+    a = bcsr_lib.from_scipy(csr, (16, 16))
+    return ops.prepare_sparse(a, dtype=jnp.float32, reorder="jaccard")
+
+
+def test_spans_reach_the_profiler_trace_while_tracing(tmp_path):
+    csr = _prepare_matrix()
+
+    def traced():
+        with trace.capture():
+            _prepare(csr)
+
+    names = _host_span_names(tmp_path, traced)
+    assert {"prepare.blocking", "prepare.reorder", "prepare.reorder.cluster",
+            "prepare.meta", "prepare.to_device"} <= names
+
+
+def test_no_spans_in_the_profiler_trace_when_disabled(tmp_path):
+    csr = _prepare_matrix()
+    trace.configure(None)
+    try:
+        names = _host_span_names(tmp_path, lambda: _prepare(csr))
+    finally:
+        trace.configure(os.environ.get("REPRO_TRACE"))
+    assert not any(n.startswith("prepare.") for n in names)
+
+
+def test_prepare_stage_gauges_cover_the_preparation():
+    csr = _prepare_matrix()
+    _prepare(csr)                                # first-call costs
+    metrics.reset()
+    t0 = time.perf_counter()
+    _prepare(csr)
+    wall = time.perf_counter() - t0
+    gauges = metrics.snapshot()["gauges"]
+    stages = [gauges[f"prepare.seconds{{stage={s}}}"]
+              for s in ("blocking", "reorder", "meta", "to_device")]
+    assert all(v > 0 for v in stages)
+    assert sum(stages) == pytest.approx(wall, rel=0.05)
+    assert "prepare.nnzb" not in "".join(gauges)
+
+
+def test_compile_listener_counts_backend_compiles():
+    x4, x5 = jnp.ones((4,)), jnp.ones((5,))
+    f = jax.jit(lambda x: jnp.sin(x) * 3 + 1)
+
+    def compiles():
+        return metrics.snapshot()["counters"].get("jax.compiles", 0)
+
+    before = compiles()
+    f(x4).block_until_ready()                    # new shape: one compile
+    assert compiles() == before + 1
+    f(x4).block_until_ready()                    # cache hit: none
+    assert compiles() == before + 1
+    f(x5).block_until_ready()
+    assert compiles() == before + 2
+    secs = metrics.snapshot()["gauges"]
+    assert secs["jax.compile.seconds{phase=compile}"] > 0
+    assert secs["jax.compile.seconds{phase=trace}"] > 0
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_spmm_hlo_names_its_steps(backend):
+    """The op's ``smat.*`` scopes: the padding of B, the kernel under the
+    backend it runs, and the epilogue (crop and un-permute after the
+    reorder).  All three reach the module handed to the compiler; the
+    compiled HLO's ``op_name`` metadata keeps each step that stays an
+    operation of its own (XLA:CPU folds the ``xla`` backend's pad into
+    the kernel's gather, which then carries the kernel's scope)."""
+    a = bcsr_lib.random_bcsr(0, (128, 64), (16, 16), 0.3)
+    arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32,
+                                      reorder="jaccard")
+    b = jnp.ones((64, 32), jnp.float32)          # N = 32: padded to 128
+    fn = jax.jit(lambda ar, bb: ops.spmm(ar, meta, bb, backend=backend,
+                                         interpret=True))
+    lowered = fn.lower(arrays, b)
+    steps = {"smat.pad", f"smat.kernel.{backend}", "smat.epilogue"}
+    assert steps <= set(re.findall(r"smat\.[a-z_.]+[a-z]",
+                                   lowered.as_text(debug_info=True)))
+    compiled = {p for name in re.findall(r'op_name="([^"]*)"',
+                                         lowered.compile().as_text())
+                for p in name.split("/") if p.startswith("smat.")}
+    kept = steps if backend == "pallas" else steps - {"smat.pad"}
+    assert kept <= compiled
