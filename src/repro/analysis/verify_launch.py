@@ -339,15 +339,22 @@ def derive_grid(meta, family: str, n: int, bn: int = 512):
 
 
 def estimate_vmem_bytes(meta, family: str, n: int, bn: int = 512) -> int:
-    """Double-buffered working-set estimate for one grid cell, from the
-    shared ``repro.analysis.workspace`` formulas (``n`` is N for the
-    spmm/sddmm families, head_dim for attn)."""
-    from repro.kernels import ops
-    if family == "attn":
+    """Working-set estimate for one grid cell (``n`` is N for the
+    spmm/sddmm families, head_dim for attn).  ``stream`` is the ``pallas``
+    SpMM kernel: its f32 accumulator, output tile and DMA ring
+    (``bcsr_spmm.nnz_stream_vmem_bytes``); the others are the
+    double-buffered cells of the shared ``repro.analysis.workspace``
+    formulas."""
+    from repro.kernels import bcsr_spmm, ops
+    bn_eff = ops._clamp_bn(bn, n)
+    if family == "stream":
         h, w = meta.block
+        return bcsr_spmm.nnz_stream_vmem_bytes(h, w, bn_eff,
+                                               meta.n_block_rows, meta.nnzb)
+    if family == "attn":
         return (workspace.attn_fused_state_bytes(meta.block, n)
-                + workspace.spmm_cell_bytes(meta.block, ops._clamp_bn(bn, n)))
-    return workspace.spmm_cell_bytes(meta.block, ops._clamp_bn(bn, n)) * 2
+                + workspace.spmm_cell_bytes(meta.block, bn_eff))
+    return workspace.spmm_cell_bytes(meta.block, bn_eff) * 2
 
 
 def _family_for(backend: str, op: str) -> Optional[str]:
@@ -380,7 +387,8 @@ def verify_launch(meta, backend: str, *, n: int, bn: int = 512,
         if any(g <= 0 for g in grid):
             errs.append(f"degenerate grid {grid} for family {family}")
     if backend in ("pallas", "row_loop", "fused"):
-        need = estimate_vmem_bytes(meta, family if family else "spmm", n, bn)
+        vmem_family = family or ("stream" if op == "spmm" else "spmm")
+        need = estimate_vmem_bytes(meta, vmem_family, n, bn)
         if need > vmem_budget:
             errs.append(
                 f"estimated VMEM working set {need} B exceeds the budget "

@@ -613,6 +613,12 @@ def resolve_backend(backend: str, bn: int, meta: SparseMeta,
     obs_trace.event("ops.dispatch", op=op, backend=backend, bn=bn, n=n,
                     nnzb=meta.nnzb, max_bpr=meta.max_bpr)
     obs_metrics.counter("ops.dispatch", op=op, backend=backend).inc()
+    if op == "spmm" and backend == "pallas":
+        # stored blocks one grid step (one row panel) of the forward
+        # kernel streams, on average
+        r = pk.panel_block_rows(meta.block[0], meta.n_block_rows)
+        obs_metrics.gauge("kernel.spmm.blocks_per_step", op=op).set(
+            meta.nnzb / -(-meta.n_block_rows // r))
     return backend, bn
 
 

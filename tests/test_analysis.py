@@ -331,6 +331,12 @@ class TestVerifier:
         assert vl.verify_launch(meta, "row_loop", n=512) == []
         errs = vl.verify_launch(meta, "row_loop", n=512, vmem_budget=1024)
         assert errs and any("VMEM" in e for e in errs)
+        # the pallas kernel: accumulator, output tile and DMA ring
+        assert vl.verify_launch(meta, "pallas", n=512) == []
+        errs = vl.verify_launch(meta, "pallas", n=512, vmem_budget=1024)
+        assert errs and any("VMEM" in e for e in errs)
+        errs = vl.verify_launch(meta, "pallas", n=8192, bn=8192)
+        assert errs and any("VMEM" in e for e in errs)
 
     def test_chunk_schedule_invariants(self):
         """Overlap schedules: every builder output passes; every corrupted

@@ -23,16 +23,25 @@ SHAPES = [
     ((96, 160), (16, 16), 0.4),
 ]
 
+# the nnz-stream kernel's row panels (R * h = 128 rows of C): 13 block-rows
+# of 16 make panels of 8 and 5; blocks of 128 rows make one block-row each
+PANEL_SHAPES = [
+    ((208, 128), (16, 16), 0.3),
+    ((384, 256), (128, 128), 0.5),
+]
 
-@pytest.mark.parametrize("shape,block,density", SHAPES)
+
+@pytest.mark.parametrize("shape,block,density", SHAPES + PANEL_SHAPES)
 @pytest.mark.parametrize("n", [8, 64])
 def test_nnz_stream_matches_ref(shape, block, density, n):
+    # bn = 32 < N = 64: two N tiles, and the copies run on from one into
+    # the next
     a = _mk(shape, block, density)
     b = np.random.default_rng(1).standard_normal(
         (shape[1], n)).astype(np.float32)
     got = pk.bcsr_spmm_nnz_stream(
         jnp.asarray(a.vals), jnp.asarray(a.row_ids), jnp.asarray(a.col_ids),
-        jnp.asarray(b), a.n_block_rows, bn=min(64, n), interpret=True)
+        jnp.asarray(b), a.n_block_rows, bn=min(32, n), interpret=True)
     want = ref.bcsr_spmm_ref(
         jnp.asarray(a.vals), jnp.asarray(a.row_ids), jnp.asarray(a.col_ids),
         jnp.asarray(b), a.n_block_rows)
@@ -53,13 +62,16 @@ def test_nnz_stream_matches_dense(shape, block, density):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
-def test_nnz_stream_dtypes(dtype):
-    shape, block = (128, 128), (16, 16)
+@pytest.mark.parametrize(
+    "dtype,block", [(np.float32, (16, 16)), (jnp.bfloat16, (16, 16)),
+                    (np.float32, (128, 128)), (jnp.bfloat16, (128, 128))],
+    ids=["float32", "bfloat16", "float32-h128", "bfloat16-h128"])
+def test_nnz_stream_dtypes(dtype, block):
+    shape = (256, 256)          # h = 16: two panels; h = 128: two block-rows
     a = _mk(shape, block, 0.3, dtype=np.float32)
     vals = jnp.asarray(a.vals).astype(dtype)
     b = jnp.asarray(np.random.default_rng(3).standard_normal(
-        (128, 64)).astype(np.float32)).astype(dtype)
+        (256, 64)).astype(np.float32)).astype(dtype)
     got = pk.bcsr_spmm_nnz_stream(
         vals, jnp.asarray(a.row_ids), jnp.asarray(a.col_ids), b,
         a.n_block_rows, bn=64, interpret=True)
@@ -69,6 +81,62 @@ def test_nnz_stream_dtypes(dtype):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         rtol=5e-2, atol=5e-2)
+
+
+def _panel_edge_case(name: str):
+    """Structures at the edges of the nnz-stream kernel's panel schedule."""
+    rng = np.random.default_rng(16)
+    if name == "one_block_per_row":
+        # 16 block-rows (two panels) of one block each, columns scattered
+        dense = np.zeros((256, 128), np.float32)
+        for i in range(16):
+            c = (5 * i) % 8
+            dense[16 * i:16 * i + 16, 16 * c:16 * c + 16] = \
+                rng.standard_normal((16, 16))
+        return bcsr_lib.from_dense(dense, (16, 16))
+    if name == "row_longer_than_ring":
+        # block-row 1 holds 32 blocks, twice the ring's 16 slots
+        dense = np.zeros((48, 512), np.float32)
+        dense[16:32] = rng.standard_normal((16, 512))
+        dense[3, 40] = 1.0
+        dense[40, 500] = -2.0
+        return bcsr_lib.from_dense(dense, (16, 16))
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("name", ["one_block_per_row",
+                                  "row_longer_than_ring"])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_nnz_stream_panel_edges(name, dtype):
+    """Checked under the TPU interpreter, which runs each DMA only when it
+    is waited for and fills uninitialised VMEM with NaN: a block read from
+    its ring slot before its copy landed, or overwritten by the next copy,
+    shows in the result."""
+    from jax.experimental.pallas import tpu as pltpu
+    a = _panel_edge_case(name)
+    vals = jnp.asarray(a.vals).astype(dtype)
+    b = jnp.asarray(np.random.default_rng(17).standard_normal(
+        (a.shape[1], 64)).astype(np.float32)).astype(dtype)
+    ids = jnp.asarray(a.row_ids), jnp.asarray(a.col_ids)
+    got = pk.bcsr_spmm_nnz_stream(
+        vals, *ids, b, a.n_block_rows, bn=32,
+        interpret=pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                        uninitialized_memory="nan"))
+    want = ref.bcsr_spmm_ref(vals, *ids, b, a.n_block_rows)
+    tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_nnz_stream_blocks_per_step_gauge():
+    from repro.obs import metrics
+    a = _mk(*PANEL_SHAPES[0])          # 13 block-rows: 2 panels of R = 8
+    arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32)
+    b = jnp.ones((a.shape[1], 8), jnp.float32)
+    ops.spmm(arrays, meta, b, backend="pallas", bn=128, interpret=True)
+    gauges = metrics.snapshot()["gauges"]
+    assert gauges["kernel.spmm.blocks_per_step{op=spmm}"] == meta.nnzb / 2
 
 
 @pytest.mark.parametrize("shape,block,density", SHAPES[:3])
@@ -135,9 +203,13 @@ def test_ops_spmm_forward(backend):
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("backend", ["pallas", "xla"])
-def test_ops_spmm_grads(backend):
-    shape, block = (64, 96), (16, 16)
+@pytest.mark.parametrize(
+    "backend,block", [("pallas", (16, 16)), ("xla", (16, 16)),
+                      ("pallas", (16, 128))],
+    ids=["pallas", "xla", "pallas-16x128"])
+def test_ops_spmm_grads(backend, block):
+    # at (16, 128) the backward streams the transpose's 128 x 16 blocks
+    shape = (64, 96) if block == (16, 16) else (96, 384)
     a = _mk(shape, block, 0.4)
     arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32)
     rng = np.random.default_rng(8)

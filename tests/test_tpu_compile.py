@@ -25,6 +25,8 @@ M, K, N = 8192, 2048, 512                 # smat FFN width, wide token panel
 NNZB = 103                                 # 10% of the 64 x 16 block grid
 MAX_BPR = 4
 ATTN_LEN, ATTN_BAND, HEADS, HEAD_DIM = 32_768, 4096, 16, 128
+# HPCG 64^3 in 16 x 128 blocks: 262,144 rows, 95,760 stored blocks
+HPCG_BLOCK, HPCG_ROWS, HPCG_NNZB = (16, 128), 262_144, 95_760
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +72,15 @@ def _kernel_cases(s):
     ids = _sds(s, (NNZB,), i32)
     sched = _sds(s, (nbr * MAX_BPR,), i32)
     vals = _sds(s, (NNZB,) + BLOCK, bf16)
+    hpcg_ids = _sds(s, (HPCG_NNZB,), i32)
+    hpcg_vals = _sds(s, (HPCG_NNZB,) + HPCG_BLOCK, bf16)
+    hpcg_nbr = HPCG_ROWS // HPCG_BLOCK[0]
     return {
+        **{f"spmm_nnz_stream_hpcg_bn{bn}": (
+            functools.partial(pk.bcsr_spmm_nnz_stream,
+                              n_block_rows=hpcg_nbr, bn=bn),
+            (hpcg_vals, hpcg_ids, hpcg_ids, _sds(s, (HPCG_ROWS, bn), bf16)))
+           for bn in (128, 512)},
         "spmm_nnz_stream": (
             functools.partial(pk.bcsr_spmm_nnz_stream, n_block_rows=nbr,
                               bn=N),
@@ -90,7 +100,9 @@ def _kernel_cases(s):
 
 
 @pytest.mark.parametrize("kernel", ["spmm_nnz_stream", "spmm_row_loop",
-                                    "sddmm", "sddmm_row_loop"])
+                                    "sddmm", "sddmm_row_loop",
+                                    "spmm_nnz_stream_hpcg_bn128",
+                                    "spmm_nnz_stream_hpcg_bn512"])
 def test_bcsr_kernel_compiles_for_v5e(one_chip, kernel):
     fn, args = _kernel_cases(one_chip)[kernel]
     assert "tpu_custom_call" in _compiled_text(fn, *args)
