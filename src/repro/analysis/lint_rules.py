@@ -70,8 +70,9 @@ _UNHASHABLE_NAMES = {"list", "List", "dict", "Dict", "set", "Set",
 # SparseMeta fields that are legitimately absent from the fingerprint:
 # ``shape`` is determined by (n_block_rows, n_block_cols, block) up to
 # ragging the N-bucket already captures; ``nnzb_t`` is derived transpose
-# bookkeeping, not a dispatch dimension.
-FINGERPRINT_FIELD_ALLOWLIST = frozenset({"shape", "nnzb_t"})
+# bookkeeping, not a dispatch dimension; a ``built="device"`` meta never
+# reaches the autotuner (``ops.resolve_backend`` picks ``DEVICE_PICK``).
+FINGERPRINT_FIELD_ALLOWLIST = frozenset({"shape", "nnzb_t", "built"})
 
 
 # ----------------------------------------------------------- AST utilities

@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnRope
 from repro.core.attention_mask import AttnSparsitySpec, banded
 from repro.core.sparse_linear import SparsitySpec
 
@@ -32,13 +32,29 @@ _register(ModelConfig(
 
 # --------------------------------------------------------------------- [moe]
 # DeepSeek-V2(-Lite), arXiv:2405.04434 — MLA kv_lora=512, shared+routed top-6
+# The published config.json: one leading dense layer (10,944 wide), then 26
+# MoE layers of 64 routed experts (1,408 wide, top-6 by softmax, weights not
+# renormalised) and 2 shared; YaRN RoPE.  Served dropless on the SMaT
+# kernels (``moe_dispatch="bcsr"``).
 _register(ModelConfig(
     name="deepseek-v2-lite-16b", family="moe", layout="mla_moe",
     n_layers=27, d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128,
-    d_ff=1408, vocab_size=102400,
+    d_ff=10944, vocab_size=102400, rope_theta=10_000.0,
+    rope_scaling=YarnRope(factor=40.0, original_max_position_embeddings=4096,
+                          beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                          mscale_all_dim=0.707),
     use_mla=True, kv_lora_rank=512, q_lora_rank=None,
     rope_head_dim=64, nope_head_dim=128, v_head_dim=128,
     n_experts=64, n_shared_experts=2, moe_top_k=6, expert_d_ff=1408,
+    first_k_dense_replace=1, norm_topk_prob=False, routed_scaling_factor=1.0,
+    moe_dispatch="bcsr",
+    source="https://huggingface.co/deepseek-ai/DeepSeek-V2-Lite/blob/main/"
+           "config.json",
+    assumed=("rope dims in the rotate-half layout: the published "
+             "interleaved layout is a fixed permutation of the wq / wkv_a "
+             "rope columns",
+             "norms scale by (1 + w) with w initialised to 0 (the published "
+             "w initialised to 1)"),
 ))
 
 _register(ModelConfig(
@@ -154,7 +170,9 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
                   rope_head_dim=16, nope_head_dim=32, v_head_dim=32)
     if cfg.n_experts:
         kw.update(n_experts=4, n_shared_experts=min(cfg.n_shared_experts, 1),
-                  moe_top_k=2, expert_d_ff=64)
+                  moe_top_k=2, expert_d_ff=64, moe_backend="xla")
+    if cfg.first_k_dense_replace:
+        kw.update(first_k_dense_replace=1)
     if cfg.ssm_state:
         kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
     if cfg.layout == "zamba":
@@ -184,4 +202,6 @@ def xla_lowered(cfg: ModelConfig) -> ModelConfig:
         spec = getattr(cfg, field)
         if spec is not None and spec.backend != "xla":
             kw[field] = dataclasses.replace(spec, backend="xla")
+    if cfg.moe_dispatch == "bcsr" and cfg.moe_backend != "xla":
+        kw["moe_backend"] = "xla"
     return dataclasses.replace(cfg, **kw) if kw else cfg

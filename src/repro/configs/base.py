@@ -9,6 +9,21 @@ from repro.core.sparse_linear import SparsitySpec
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN RoPE scaling (arXiv:2309.00071) as DeepSeek-V2's
+    ``rope_scaling`` states it (``type: yarn``): rotary frequencies blended
+    between extrapolation and interpolation by ``factor`` over the
+    correction range set by ``beta_fast``/``beta_slow``, and the softmax
+    scale multiplied by ``mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                 # dense | moe | ssm | hybrid | vlm | audio
@@ -23,6 +38,7 @@ class ModelConfig:
 
     # --- attention details
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnRope] = None  # YaRN (deepseek-v2), or plain
     qkv_bias: bool = False
     sliding_window: Optional[int] = None     # SWA window (h2o-danube, gemma2 local)
     attn_logit_softcap: Optional[float] = None
@@ -41,8 +57,13 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_top_k: int = 0
     expert_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # gather / einsum only; bcsr drops none
     moe_dispatch: str = "gather"    # gather (default) | einsum (GShard arm)
+                                    # | bcsr (dropless, SDDMM/SpMM kernels)
+    moe_backend: str = "auto"       # bcsr: ops.sddmm / ops.spmm backend
+    norm_topk_prob: bool = True     # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0
+    first_k_dense_replace: int = 0  # leading layers with a dense FFN (d_ff)
 
     # --- SSM (mamba2 / zamba2)
     ssm_state: int = 0
@@ -72,6 +93,11 @@ class ModelConfig:
 
     dtype: str = "bfloat16"
     mlp_act: str = "silu"           # silu (gated) | gelu (gated, gemma2)
+
+    # --- provenance: the published config this follows, and where it differs
+    source: str = ""                # URL of the published config
+    reduced: Tuple[str, ...] = ()   # keys cut from the published values
+    assumed: Tuple[str, ...] = ()   # sizes/choices the source does not give
 
     # ------------------------------------------------------------------ props
     @property
@@ -116,7 +142,10 @@ class ModelConfig:
             n += n_mamba * _ssd_params(self)
             n += _attn_params(self) + _mlp_params(self)  # shared block (once)
         elif self.layout == "mla_moe":
-            n += self.n_layers * (_mla_params(self) + _moe_params(self))
+            k = self.first_k_dense_replace
+            n += self.n_layers * _mla_params(self)
+            n += k * _mlp_params(self) + (self.n_layers - k) * \
+                _moe_params(self)
         elif self.layout == "gemma_pair":
             n += self.n_layers * (_attn_params(self) + _mlp_params(self))
         else:
@@ -128,10 +157,11 @@ class ModelConfig:
         if self.layout != "mla_moe":
             return self.param_count()
         d = self.d_model
+        k = self.first_k_dense_replace
         active_experts = self.moe_top_k + self.n_shared_experts
-        per_layer = _mla_params(self) + \
-            3 * d * self.expert_d_ff * active_experts + d * self.n_experts
-        return self.vocab_size * d * 2 + self.n_layers * per_layer
+        moe = 3 * d * self.expert_d_ff * active_experts + d * self.n_experts
+        return self.vocab_size * d * 2 + self.n_layers * _mla_params(self) \
+            + k * _mlp_params(self) + (self.n_layers - k) * moe
 
 
 def _attn_params(cfg: ModelConfig) -> int:

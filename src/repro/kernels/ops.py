@@ -89,6 +89,9 @@ class SparseMeta:
                                     # (launch.dist_spmm) — fingerprinted so
                                     # per-shard picks never alias the
                                     # unsharded twin's cache entries
+    built: str = "host"             # host (prepare_sparse) | device
+                                    # (device_sparse: rebuilt every step,
+                                    # static capacity, ``auto`` is static)
 
     @property
     def row_loop_sched_len(self) -> int:
@@ -293,6 +296,56 @@ def prepare(a: bcsr_lib.BCSR, dtype=jnp.bfloat16, *,
     if meta_only:
         return prepare_sparse_meta(a, **kw)
     return prepare_sparse(a, dtype, **kw)
+
+
+# the static ``auto`` pick (backend, bn) of a device-built structure, for
+# both ops: its blocks change every step, so no fingerprint of them exists
+# to look up
+DEVICE_PICK = ("pallas", 512)
+
+
+def device_sparse(row_ids: jnp.ndarray, col_ids: jnp.ndarray,
+                  real_mask: jnp.ndarray, *, n_block_rows: int,
+                  n_block_cols: int, block: Tuple[int, int],
+                  max_bpr: int = 0) -> Tuple[SparseArrays, SparseMeta]:
+    """A structure built on the device inside a traced step (as the
+    dropless MoE builds its expert blocks), where ``prepare_sparse`` builds
+    one on the host.  ``row_ids`` / ``col_ids`` are traced ``[nnzb]``
+    int32 with ``row_ids`` sorted; ``nnzb`` is a static capacity whose
+    unused entries carry ``real_mask`` False (``sddmm`` zeroes them, so
+    ``spmm`` of the sampled blocks adds nothing for them).
+
+    The transpose structure for the backward pass is built here too,
+    with one sentinel entry (the zero block ``nnzb``) on every block-row
+    of A^T, so the ``nnzb_t = nnzb + n_block_cols`` entries keep every
+    A^T block-row nonempty whatever the routing.  ``vals`` is None: the
+    caller puts the values in (``arrays._replace(vals=...)``).  The meta
+    says ``built="device"``, which ``auto`` resolves to ``DEVICE_PICK``.
+    """
+    nnzb = int(row_ids.shape[0])
+    if n_block_cols * (n_block_rows + 1) >= 2 ** 31:
+        raise ValueError("structure too large for int32 transpose keys")
+    t_row = jnp.concatenate([col_ids, jnp.arange(n_block_cols,
+                                                 dtype=jnp.int32)])
+    t_col = jnp.concatenate([row_ids, jnp.zeros((n_block_cols,),
+                                                jnp.int32)])
+    t_perm = jnp.concatenate([jnp.arange(nnzb, dtype=jnp.int32),
+                              jnp.full((n_block_cols,), nnzb, jnp.int32)])
+    # entries of a block-row of A^T in A^T column order, its sentinel last
+    last = jnp.concatenate([t_col[:nnzb], jnp.full((n_block_cols,),
+                                                   n_block_rows, jnp.int32)])
+    order = jnp.argsort(t_row * (n_block_rows + 1) + last)
+    arrays = SparseArrays(
+        vals=None, row_ids=row_ids, col_ids=col_ids, real_mask=real_mask,
+        t_perm=t_perm[order], t_row_ids=t_row[order],
+        t_col_ids=t_col[order])
+    h, w = block
+    meta = SparseMeta(shape=(n_block_rows * h, n_block_cols * w),
+                      block=tuple(block), n_block_rows=n_block_rows,
+                      n_block_cols=n_block_cols, nnzb=nnzb,
+                      nnzb_t=nnzb + n_block_cols, max_bpr=max_bpr,
+                      built="device")
+    return arrays, meta
 
 
 # ------------------------------------------------------------ forward pieces
@@ -583,7 +636,9 @@ def resolve_backend(backend: str, bn: int, meta: SparseMeta,
     share backend strings but fingerprint separately (v6 ``op=`` field),
     so an SpMM pick can never alias an SDDMM one.
     """
-    if backend == "auto":
+    if backend == "auto" and meta.built == "device":
+        backend, bn = DEVICE_PICK
+    elif backend == "auto":
         from repro.kernels import autotune  # local import: avoids cycle
         choice = autotune.get_autotuner().pick(meta, n, op=op)
         backend, bn = choice.backend, choice.bn

@@ -66,11 +66,16 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig,
     return train_step
 
 
-def make_prefill_step(cfg: ModelConfig, cache_len: int):
+def make_prefill_step(cfg: ModelConfig, cache_len: int, stats: bool = False,
+                      capture: bool = False):
+    """``(params, batch) -> (last-position logits, cache)``: what serving
+    samples from; the head runs at the last position only.  ``stats`` adds
+    the MoE layers' routing stats third (``models.moe.moe_layer``), and
+    ``capture`` each MoE layer's routed input, gate weights and routed
+    output to them, for checking the routed experts alone."""
     def prefill_step(params, batch):
-        logits, cache = T.prefill(cfg, params, batch, cache_len)
-        # return just the last-position logits (what serving samples from)
-        return logits[:, -1], cache
+        return T.prefill(cfg, params, batch, cache_len,
+                         stats=stats or capture, capture=capture)
     return prefill_step
 
 

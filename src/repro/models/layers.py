@@ -20,10 +20,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 from repro.core.sparse_linear import (apply_sparse_linear,
@@ -56,12 +58,58 @@ def _rope_freqs(head_dim: int, theta: float):
                                        dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
-    """x [..., L, H, dh]; positions [..., L] int32 (broadcastable)."""
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """DeepSeek's ``yarn_get_mscale``: the attention temperature YaRN
+    applies at context-extension ``scale``."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(yarn, dim: int, theta: float):
+    """The rotary dims ``[low, high]`` between which YaRN blends from
+    extrapolated to interpolated frequencies (DeepSeek's
+    ``yarn_find_correction_range``)."""
+    def dim_of(rotations):
+        return dim * math.log(yarn.original_max_position_embeddings /
+                              (rotations * 2 * math.pi)) / \
+            (2 * math.log(theta))
+    low = math.floor(dim_of(yarn.beta_fast))
+    high = math.ceil(dim_of(yarn.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_freqs(dim: int, theta: float, yarn) -> np.ndarray:
+    """[dim/2] float32 inverse frequencies of DeepSeek's
+    ``DeepseekV2YarnRotaryEmbedding``: ``freq / factor`` below the
+    correction range, ``freq`` above it, a linear ramp between."""
+    freq = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    low, high = yarn_correction_range(yarn, dim, theta)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) /
+                   (high - low), 0, 1)
+    extra = 1.0 - ramp
+    return (freq / yarn.factor * (1 - extra) + freq * extra).astype(
+        np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn=None):
+    """x [..., L, H, dh]; positions [..., L] int32 (broadcastable).
+    ``yarn`` (a ``configs.base.YarnRope``) switches to YaRN frequencies,
+    with cos/sin scaled by ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)`` as DeepSeek does (1 when the two are equal)."""
     dh = x.shape[-1]
-    freqs = _rope_freqs(dh, theta)                       # [dh/2]
+    if yarn is None:
+        freqs = _rope_freqs(dh, theta)                   # [dh/2]
+        mag = 1.0
+    else:
+        freqs = jnp.asarray(yarn_freqs(dh, theta, yarn))
+        mag = yarn_mscale(yarn.factor, yarn.mscale) / \
+            yarn_mscale(yarn.factor, yarn.mscale_all_dim)
     ang = positions[..., None].astype(jnp.float32) * freqs   # [..., L, dh/2]
     sin, cos = jnp.sin(ang)[..., None, :], jnp.cos(ang)[..., None, :]
+    if mag != 1.0:
+        sin, cos = sin * mag, cos * mag
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -366,9 +414,24 @@ def init_mla(cfg, key, dtype):
     return p
 
 
+def mla_softmax_scale(cfg) -> float:
+    """``(nope + rope) ** -0.5``, times ``mscale(factor, mscale_all_dim)
+    ** 2`` under YaRN (DeepSeek's ``softmax_scale``)."""
+    scale = (cfg.nope_head_dim + cfg.rope_head_dim) ** -0.5
+    yarn = cfg.rope_scaling
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_attention(cfg, p, x, *, cache=None, pos=None):
     """Multi-head Latent Attention.  Cache holds the compressed latent
     (c_kv, k_rope) only — decode uses the absorbed-matrix form."""
+    with jax.named_scope("model.mla"):
+        return _mla_attention(cfg, p, x, cache=cache, pos=pos)
+
+
+def _mla_attention(cfg, p, x, *, cache=None, pos=None):
     B, L, D = x.shape
     h = cfg.n_heads
     nope, rope, vd, r = (cfg.nope_head_dim, cfg.rope_head_dim,
@@ -391,10 +454,10 @@ def mla_attention(cfg, p, x, *, cache=None, pos=None):
     else:
         positions = (pos + jnp.arange(L, dtype=jnp.int32))[None, :]
     positions = jnp.broadcast_to(positions, (B, L))
-    q_rope = apply_rope(q_rope, positions, theta)
-    k_rope = apply_rope(k_rope, positions, theta)
+    q_rope = apply_rope(q_rope, positions, theta, cfg.rope_scaling)
+    k_rope = apply_rope(k_rope, positions, theta, cfg.rope_scaling)
 
-    scale = (nope + rope) ** -0.5
+    scale = mla_softmax_scale(cfg)
     w_kv_b = p["wkv_b"].reshape(r, h, nope + vd)
     w_uk, w_uv = w_kv_b[..., :nope], w_kv_b[..., nope:]
 

@@ -6,7 +6,9 @@ Layouts:
   attn_mlp    — standard decoder (dense archs, pixtral/musicgen backbones,
                 smat_ffn with block-sparse FFN)
   gemma_pair  — (local SWA + global) pair scanned n_layers/2 times, softcaps
-  mla_moe     — DeepSeek MLA attention + shared/routed MoE FFN
+  mla_moe     — DeepSeek MLA attention + shared/routed MoE FFN, after
+                ``first_k_dense_replace`` leading MLA + dense-FFN layers
+                (their own stack, ``params["dense_blocks"]``)
   ssd         — Mamba2 (attention-free)
   zamba       — units of (unit_len x mamba2) + ONE shared attention block
                 (params reused across units) + mamba tail
@@ -14,7 +16,7 @@ Layouts:
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +62,12 @@ def _init_block(cfg: ModelConfig, key, dtype, seed_hint: int = 0):
                 "mla": L.init_mla(cfg, k1, dtype),
                 "ln2": jnp.zeros((cfg.d_model,), jnp.float32),
                 "moe": M.init_moe(cfg, k2, dtype)}
+    if cfg.layout == "mla_dense":
+        k1, k2 = jax.random.split(key)
+        return {"ln1": jnp.zeros((cfg.d_model,), jnp.float32),
+                "mla": L.init_mla(cfg, k1, dtype),
+                "ln2": jnp.zeros((cfg.d_model,), jnp.float32),
+                "mlp": L.init_mlp(cfg, k2, dtype)}
     if cfg.layout == "ssd":
         return {"ln": jnp.zeros((cfg.d_model,), jnp.float32),
                 "ssd": S.init_ssd(cfg, key, dtype)}
@@ -77,8 +85,10 @@ def _mlp_seed_hints(cfg: ModelConfig):
     return (0,)
 
 
-def _apply_block(cfg: ModelConfig, p, x, cache, pos):
-    """Returns (x, new_cache, aux)."""
+def _apply_block(cfg: ModelConfig, p, x, cache, pos, capture: bool = False):
+    """Returns (x, new_cache, aux, stats); ``stats`` is ``{}`` except for
+    a ``bcsr`` MoE block (``moe.moe_layer``, which ``capture`` is passed
+    to)."""
     from repro.launch.constrain import BATCH, MODEL, constrain
     if x.shape[1] > 1:
         # sequence-parallel carry (Megatron-SP): norms/FFN run L-sharded;
@@ -91,7 +101,7 @@ def _apply_block(cfg: ModelConfig, p, x, cache, pos):
         x = x + a
         x = x + L.mlp(cfg, p["mlp"], L.rms_norm(x, p["ln2"]),
                       seed_hints=_mlp_seed_hints(cfg))
-        return x, c, aux
+        return x, c, aux, {}
     if cfg.layout == "gemma_pair":
         caches = cache or {"local": None, "global": None}
         new_c = {}
@@ -104,19 +114,23 @@ def _apply_block(cfg: ModelConfig, p, x, cache, pos):
                       seed_hints=_mlp_seed_hints(cfg))
             x = x + L.rms_norm(m, h["ln2_post"])
             new_c[kind] = c
-        return x, (new_c if cache is not None else None), aux
-    if cfg.layout == "mla_moe":
+        return x, (new_c if cache is not None else None), aux, {}
+    if cfg.layout in ("mla_moe", "mla_dense"):
         a, c = L.mla_attention(cfg, p["mla"], L.rms_norm(x, p["ln1"]),
                                cache=cache, pos=pos)
         x = x + a
-        y, aux = M.moe_ffn(cfg, p["moe"], L.rms_norm(x, p["ln2"]),
-                           dispatch=cfg.moe_dispatch)
-        x = x + y
-        return x, c, aux
+        if cfg.layout == "mla_dense":
+            with jax.named_scope("model.dense_ffn"):
+                x = x + L.mlp(cfg, p["mlp"], L.rms_norm(x, p["ln2"]))
+            return x, c, aux, {}
+        y, aux, stats = M.moe_layer(cfg, p["moe"], L.rms_norm(x, p["ln2"]),
+                                    dispatch=cfg.moe_dispatch,
+                                    capture=capture)
+        return x + y, c, aux, stats
     if cfg.layout == "ssd":
         y, c = S.ssd_block(cfg, p["ssd"], L.rms_norm(x, p["ln"]),
                            cache=cache, pos=pos)
-        return x + y, c, aux
+        return x + y, c, aux, {}
     raise ValueError(cfg.layout)
 
 
@@ -128,7 +142,7 @@ def _block_cache(cfg: ModelConfig, batch, cache_len, dtype):
         return {"local": L.init_attn_cache(cfg, batch, cache_len, dtype,
                                            window=cfg.sliding_window),
                 "global": L.init_attn_cache(cfg, batch, cache_len, dtype)}
-    if cfg.layout == "mla_moe":
+    if cfg.layout in ("mla_moe", "mla_dense"):
         return L.init_mla_cache(cfg, batch, cache_len, dtype)
     if cfg.layout == "ssd":
         return S.init_ssd_cache(cfg, batch, dtype)
@@ -138,13 +152,35 @@ def _block_cache(cfg: ModelConfig, batch, cache_len, dtype):
 def _n_repeats(cfg: ModelConfig) -> int:
     if cfg.layout == "gemma_pair":
         return cfg.n_layers // 2
+    if cfg.layout == "mla_moe":
+        return cfg.n_layers - cfg.first_k_dense_replace
+    if cfg.layout == "mla_dense":
+        return cfg.first_k_dense_replace
     return cfg.n_layers
 
 
+@functools.lru_cache(maxsize=None)
+def _dense_view(cfg: ModelConfig) -> ModelConfig:
+    """The leading dense layers of an ``mla_moe`` config as a layout of
+    their own (MLA + the ``d_ff`` gated MLP)."""
+    import dataclasses
+    return dataclasses.replace(cfg, layout="mla_dense")
+
+
+def _stacks(cfg: ModelConfig):
+    """``(params key, view cfg)`` of each scanned stack, in order."""
+    if cfg.layout == "mla_moe" and cfg.first_k_dense_replace:
+        return (("dense_blocks", _dense_view(cfg)), ("blocks", cfg))
+    return (("blocks", cfg),)
+
+
 # ============================================================= params (full)
-def init_params(cfg: ModelConfig, seed: int = 0) -> Dict[str, Any]:
+def init_params(cfg: ModelConfig, seed: int = 0, key=None) -> Dict[str, Any]:
+    """Random parameters from ``seed``, or from the PRNG ``key`` when given
+    (a traced key lets one jitted init serve every seed)."""
     dtype = _dtype(cfg)
-    key = jax.random.PRNGKey(seed)
+    if key is None:
+        key = jax.random.PRNGKey(seed)
     d = cfg.d_model
     k_embed, k_head, k_blocks, k_shared = jax.random.split(key, 4)
     params: Dict[str, Any] = {
@@ -185,11 +221,14 @@ def init_params(cfg: ModelConfig, seed: int = 0) -> Dict[str, Any]:
             "ln2": jnp.zeros((d,), jnp.float32),
             "mlp": L.init_mlp(cfg, k2, dtype)}
     else:
-        n = _n_repeats(cfg)
-        keys = jax.random.split(k_blocks, n)
-        params["blocks"] = _stack(
-            [_init_block(cfg, keys[i], dtype, seed_hint=i)
-             for i in range(n)])
+        stacks = _stacks(cfg)
+        keys = list(jax.random.split(
+            k_blocks, sum(_n_repeats(view) for _, view in stacks)))
+        for name, view in stacks:
+            n = _n_repeats(view)
+            params[name] = _stack(
+                [_init_block(view, keys.pop(0), dtype, seed_hint=i)
+                 for i in range(n)])
     return params
 
 
@@ -215,10 +254,16 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int):
         }
     # one zeroed buffer per leaf: stacking per-layer zeros would hold the
     # whole cache twice at its peak (the full-width KV cache is GBs)
-    n = _n_repeats(cfg)
-    one = jax.eval_shape(
-        functools.partial(_block_cache, cfg, batch, cache_len, dtype))
-    return jax.tree.map(lambda s: jnp.zeros((n,) + s.shape, s.dtype), one)
+    def stacked(view):
+        n = _n_repeats(view)
+        one = jax.eval_shape(
+            functools.partial(_block_cache, view, batch, cache_len, dtype))
+        return jax.tree.map(lambda s: jnp.zeros((n,) + s.shape, s.dtype),
+                            one)
+    stacks = _stacks(cfg)
+    if len(stacks) == 1:
+        return stacked(cfg)
+    return {name: stacked(view) for name, view in stacks}
 
 
 def cache_specs(cfg: ModelConfig, batch: int, cache_len: int):
@@ -244,17 +289,19 @@ def _embed(cfg: ModelConfig, params, batch_in) -> jnp.ndarray:
 
 
 def _head(cfg: ModelConfig, params, x) -> jnp.ndarray:
-    x = L.rms_norm(x, params["final_norm"])
-    if cfg.input_mode == "codebooks":
-        logits = jnp.einsum("bld,cdv->blcv", x, params["lm_head"])
-    else:
-        logits = jnp.einsum("bld,dv->blv", x, params["lm_head"])
-    return L.softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
+    with jax.named_scope("model.head"):
+        x = L.rms_norm(x, params["final_norm"])
+        if cfg.input_mode == "codebooks":
+            logits = jnp.einsum("bld,cdv->blcv", x, params["lm_head"])
+        else:
+            logits = jnp.einsum("bld,dv->blv", x, params["lm_head"])
+        return L.softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
 
 
-def _scan_stack(cfg, stacked, x, caches, pos, remat: str):
+def _scan_stack(cfg, stacked, x, caches, pos, remat: str,
+                capture: bool = False):
     """Scan blocks over the leading stack axis; caches ride as xs/ys."""
-    fn = functools.partial(_apply_block, cfg)
+    fn = functools.partial(_apply_block, cfg, capture=capture)
     if remat == "full":
         fn = jax.checkpoint(fn)
     elif remat == "dots":
@@ -270,20 +317,37 @@ def _scan_stack(cfg, stacked, x, caches, pos, remat: str):
     if caches is None:
         def body(carry, p):
             x, aux = carry
-            x2, _, a = fn(p, x, None, None)
-            return (x2, aux + a), None
-        (x, aux), _ = U.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   stacked)
-        return x, None, aux
+            x2, _, a, st = fn(p, x, None, None)
+            return (x2, aux + a), st
+        (x, aux), stats = U.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                 stacked)
+        return x, None, aux, stats
 
     def body(carry, xs):
         x, aux = carry
         p, c = xs
-        x2, c2, a = fn(p, x, c, pos)
-        return (x2, aux + a), c2
-    (x, aux), new_caches = U.scan(
+        x2, c2, a, st = fn(p, x, c, pos)
+        return (x2, aux + a), (c2, st)
+    (x, aux), (new_caches, stats) = U.scan(
         body, (x, jnp.zeros((), jnp.float32)), (stacked, caches))
-    return x, new_caches, aux
+    return x, new_caches, aux, stats
+
+
+def _forward_stacks(cfg, params, x, caches, pos, remat, capture=False):
+    """Every scanned stack in turn (``_stacks``).  Returns ``(x, caches,
+    aux, stats)``, ``stats`` the per-layer ``moe_layer`` stats stacked
+    over the layers (``{}`` without a ``bcsr`` MoE)."""
+    stacks = _stacks(cfg)
+    if len(stacks) == 1:
+        return _scan_stack(cfg, params["blocks"], x, caches, pos, remat,
+                           capture)
+    aux, stats, new_caches = jnp.zeros((), jnp.float32), {}, {}
+    for name, view in stacks:
+        c = None if caches is None else caches[name]
+        x, new_caches[name], a, st = _scan_stack(view, params[name], x, c,
+                                                 pos, remat, capture)
+        aux, stats = aux + a, {**stats, **st}
+    return x, (None if caches is None else new_caches), aux, stats
 
 
 def _zamba_forward(cfg, params, x, caches, pos, remat):
@@ -304,10 +368,11 @@ def _zamba_forward(cfg, params, x, caches, pos, remat):
             x2 = carry2
             if c_ssd is None:
                 p2 = xs2
-                y, _, _ = _apply_block(_ssd_view(cfg), p2, x2, None, None)
+                y, _, _, _ = _apply_block(_ssd_view(cfg), p2, x2, None,
+                                          None)
                 return y, None
             p2, cc = xs2
-            y, cc2, _ = _apply_block(_ssd_view(cfg), p2, x2, cc, pos)
+            y, cc2, _, _ = _apply_block(_ssd_view(cfg), p2, x2, cc, pos)
             return y, cc2
 
         if c_ssd is None:
@@ -328,14 +393,14 @@ def _zamba_forward(cfg, params, x, caches, pos, remat):
 
     if caches is None:
         (x, aux), _ = U.scan(unit_body, (x, aux0), params["units"])
-        x, _, _ = _scan_stack(_ssd_view(cfg), params["tail"], x, None, pos,
-                              remat)
+        x, _, _, _ = _scan_stack(_ssd_view(cfg), params["tail"], x, None,
+                                 pos, remat)
         return x, None, aux
     (x, aux), (u_ssd, u_attn) = U.scan(
         unit_body, (x, aux0),
         (params["units"], caches["units_ssd"], caches["units_attn"]))
-    x, tail_c, _ = _scan_stack(_ssd_view(cfg), params["tail"], x,
-                               caches["tail_ssd"], pos, remat)
+    x, tail_c, _, _ = _scan_stack(_ssd_view(cfg), params["tail"], x,
+                                  caches["tail_ssd"], pos, remat)
     new_caches = {"units_ssd": u_ssd, "units_attn": u_attn,
                   "tail_ssd": tail_c}
     return x, new_caches, aux
@@ -352,16 +417,25 @@ def _ssd_view(cfg):
 
 
 def forward(cfg: ModelConfig, params, batch_in, *, cache=None, pos=None,
-            remat: str = "none") -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
-    """Returns (logits, new_cache, aux_loss)."""
+            remat: str = "none", last_only: bool = False,
+            stats: bool = False, capture: bool = False):
+    """Returns (logits, new_cache, aux_loss), and the MoE ``stats`` (see
+    ``_forward_stacks``) fourth when ``stats`` is set; ``capture`` adds
+    each MoE layer's routed input, gate weights and routed output to them
+    (``moe.moe_layer``).  ``last_only`` computes the head at the last
+    position alone (logits ``[B, 1, V]``)."""
     from repro.launch.constrain import BATCH, constrain
     x = constrain(_embed(cfg, params, batch_in), BATCH)
+    st = {}
     if cfg.layout == "zamba":
         x, new_cache, aux = _zamba_forward(cfg, params, x, cache, pos, remat)
     else:
-        x, new_cache, aux = _scan_stack(cfg, params["blocks"], x, cache, pos,
-                                        remat)
-    return _head(cfg, params, x), new_cache, aux
+        x, new_cache, aux, st = _forward_stacks(cfg, params, x, cache, pos,
+                                                remat, capture)
+    if last_only:
+        x = x[:, -1:]
+    out = (_head(cfg, params, x), new_cache, aux)
+    return out + (st,) if stats else out
 
 
 # ================================================================ entry points
@@ -383,13 +457,19 @@ def train_loss(cfg: ModelConfig, params, batch_in, remat: str = "full"):
     return loss + 0.01 * aux, {"lm_loss": loss, "aux_loss": aux}
 
 
-def prefill(cfg: ModelConfig, params, batch_in, cache_len: int):
-    """Build decode caches from a prompt.  Returns (logits, cache)."""
+def prefill(cfg: ModelConfig, params, batch_in, cache_len: int,
+            stats: bool = False, capture: bool = False):
+    """Build decode caches from a prompt.  Returns (logits, cache), the
+    logits of the last position only (``[B, V]``, or ``[B, n_cb, V]``),
+    and the MoE ``stats`` third when ``stats`` is set (with ``capture``'s
+    additions, see ``forward``)."""
     B = batch_in["tokens"].shape[0]
     cache = init_cache(cfg, B, cache_len)
-    logits, new_cache, _ = forward(cfg, params, batch_in, cache=cache,
-                                   pos=jnp.zeros((), jnp.int32))
-    return logits, new_cache
+    logits, new_cache, _, st = forward(
+        cfg, params, batch_in, cache=cache, pos=jnp.zeros((), jnp.int32),
+        last_only=True, stats=True, capture=capture)
+    out = (logits[:, 0], new_cache)
+    return out + (st,) if stats else out
 
 
 def decode_step(cfg: ModelConfig, params, cache, tokens, pos):
