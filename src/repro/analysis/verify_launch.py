@@ -342,15 +342,21 @@ def estimate_vmem_bytes(meta, family: str, n: int, bn: int = 512) -> int:
     """Working-set estimate for one grid cell (``n`` is N for the
     spmm/sddmm families, head_dim for attn).  ``stream`` is the ``pallas``
     SpMM kernel: its f32 accumulator, output tile and DMA ring
-    (``bcsr_spmm.nnz_stream_vmem_bytes``); the others are the
+    (``bcsr_spmm.nnz_stream_vmem_bytes``); ``sddmm_stream`` the ``pallas``
+    SDDMM kernel: the panel's X, the ring of Y slabs and the ring of output
+    blocks over N padded to whole lanes (``bcsr_spmm.
+    sddmm_stream_vmem_bytes``; ``bn`` plays no part); the others are the
     double-buffered cells of the shared ``repro.analysis.workspace``
     formulas."""
     from repro.kernels import bcsr_spmm, ops
     bn_eff = ops._clamp_bn(bn, n)
+    h, w = meta.block
     if family == "stream":
-        h, w = meta.block
         return bcsr_spmm.nnz_stream_vmem_bytes(h, w, bn_eff,
                                                meta.n_block_rows, meta.nnzb)
+    if family == "sddmm_stream":
+        return bcsr_spmm.sddmm_stream_vmem_bytes(
+            h, w, -(-n // 128) * 128, meta.n_block_rows, meta.nnzb)
     if family == "attn":
         return (workspace.attn_fused_state_bytes(meta.block, n)
                 + workspace.spmm_cell_bytes(meta.block, bn_eff))
@@ -387,7 +393,8 @@ def verify_launch(meta, backend: str, *, n: int, bn: int = 512,
         if any(g <= 0 for g in grid):
             errs.append(f"degenerate grid {grid} for family {family}")
     if backend in ("pallas", "row_loop", "fused"):
-        vmem_family = family or ("stream" if op == "spmm" else "spmm")
+        vmem_family = family or {"spmm": "stream",
+                                 "sddmm": "sddmm_stream"}.get(op, "spmm")
         need = estimate_vmem_bytes(meta, vmem_family, n, bn)
         if need > vmem_budget:
             errs.append(

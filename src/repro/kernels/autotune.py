@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.core import bcsr as bcsr_lib
 from repro.core import perf_model as pm
+from repro.kernels import bcsr_spmm as pk
 from repro.kernels import ops
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -140,12 +141,21 @@ register_variant(KernelVariant(
     description="materialized dense GEMM (cuBLAS arm; wins at high density)"))
 
 
-# SDDMM family (ops.sddmm): X @ Y^T sampled at the stored blocks.  The
-# contraction runs over N (the bn-tiled axis), so the per-block elementary
-# cost matches the SpMM block roofline with the same (h, w, bn) tile.
+# SDDMM family (ops.sddmm): X @ Y^T sampled at the stored blocks, the
+# contraction over N.
 def _t_sddmm_stream(meta: ops.SparseMeta, n: int, bn: int) -> float:
+    # one grid step per row panel (and contraction chunk, where the panel's
+    # X does not fit VMEM whole): each block's Y slab streams once, the
+    # panel's X rows and each output block once; bn plays no part
     h, w = meta.block
-    return pm.spmm_model_time(meta.nnzb * _n_tiles(n, bn), h, w, bn)
+    chunk, n_chunks = pk.sddmm_chunks(h, w, -(-n // 128) * 128,
+                                      meta.n_block_rows)
+    n = chunk * n_chunks
+    flops = 2.0 * meta.nnzb * h * w * n
+    moved = _bytes_per_el() * (meta.nnzb * w * n + meta.n_block_rows * h * n
+                               + meta.nnzb * h * w * n_chunks)
+    return (max(flops / pm.PEAK_FLOPS_BF16, moved / pm.HBM_BW)
+            + n_chunks * _T_INIT)
 
 
 def _t_sddmm_row_loop(meta: ops.SparseMeta, n: int, bn: int) -> float:
@@ -170,8 +180,8 @@ def _t_sddmm_dense(meta: ops.SparseMeta, n: int, bn: int) -> float:
 
 register_variant(KernelVariant(
     name="sddmm_stream", backend="pallas", op="sddmm",
-    bn_candidates=(128, 256, 512, 1024), model_time=_t_sddmm_stream,
-    description="nonzero-block-streamed Pallas SDDMM (skew-immune)"))
+    bn_candidates=(512,), model_time=_t_sddmm_stream,
+    description="row-panel-streamed Pallas SDDMM (skew-immune)"))
 register_variant(KernelVariant(
     name="sddmm_row_loop", backend="row_loop", op="sddmm",
     bn_candidates=(128, 256, 512), model_time=_t_sddmm_row_loop,
@@ -247,7 +257,7 @@ class Fingerprint:
     must never share a cached ``row_loop`` decision.  ``op`` (v5) names
     the compute family: ``ops.spmm`` and ``ops.sddmm`` dispatch over the
     SAME structures with different optimal schedules (SDDMM contracts
-    over the bn-tiled N axis instead of streaming it), so their picks
+    over N instead of streaming it), so their picks
     must never alias.  v6 adds the ``attn`` family (fused one-kernel
     attention vs the composed triple — a third disjoint pick space over
     the same structures) and bumps the key prefix so v5 caches, which

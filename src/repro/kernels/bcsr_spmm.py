@@ -19,10 +19,14 @@ Four kernels:
     baseline in benchmarks).
 
   * ``bcsr_sddmm``            — block-sampled dense-dense product
-    (``X @ Y^T`` evaluated only at the stored blocks), streamed over the
-    nonzero-block list.  It is both the backward pass of SpMM (dW of a
-    sparse weight) and, since PR 5, the forward of the public
-    ``ops.sddmm`` — the score kernel of block-sparse attention.
+    (``X @ Y^T`` evaluated only at the stored blocks) on the same row
+    panels: one grid step holds its panel's rows of X over the whole
+    contraction in VMEM and streams its blocks' Y slabs through a ring of
+    VMEM slots, each block one f32 contraction written out by DMA; a
+    contraction too wide for VMEM is cut into chunks, one launch each.
+    It is both the backward pass of SpMM (dW of a sparse weight) and the
+    forward of the public ``ops.sddmm`` — the score kernel of block-sparse
+    attention and the dropless MoE's gate and up products.
 
   * ``bcsr_sddmm_row_loop``   — the paper-faithful static-schedule SDDMM
     twin: one grid cell per (block-row x slot x N-tile), looping to
@@ -254,61 +258,162 @@ def bcsr_spmm_row_loop(vals: jnp.ndarray, flat_idx: jnp.ndarray,
 
 
 # ===================================================================== SDDMM
-def _sddmm_kernel(row_ref, col_ref, dc_ref, b_ref, dv_ref, acc_ref,
-                  *, n_tiles: int):
-    j = pl.program_id(1)
+def sddmm_chunks(h: int, w: int, n: int, n_block_rows: int,
+                 x_bytes: int = 2, y_bytes: int = 2) -> tuple[int, int]:
+    """``(chunk, n_chunks)``: the contraction columns one grid step of
+    ``bcsr_sddmm`` holds.  All of N while the panel's X, double-buffered,
+    fits half the ring's 4 MiB and a Y slab a quarter (so the ring keeps
+    four slots or more); else the fewest 128-column chunks that do, the
+    last padded with zeros."""
+    rows = panel_block_rows(h, n_block_rows) * h
+    fit = _RING_BYTES // (4 * max(rows * x_bytes, w * y_bytes))
+    if n <= fit:
+        return n, 1
+    fit = max(128, fit // 128 * 128)
+    n_chunks = -(-n // fit)
+    return -(-n // (128 * n_chunks)) * 128, n_chunks
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # [h, bn] x [w, bn]^T -> [h, w]
-    acc_ref[...] += jax.lax.dot_general(
-        dc_ref[...], b_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+def sddmm_stream_vmem_bytes(h: int, w: int, n: int, n_block_rows: int,
+                            nnzb: int) -> int:
+    """VMEM working set of ``bcsr_sddmm`` on bf16 operands: the panel's X
+    (double-buffered), the ring of Y slabs and the ring of output blocks
+    (f32 where the contraction is chunked)."""
+    rows = panel_block_rows(h, n_block_rows) * h
+    chunk, n_chunks = sddmm_chunks(h, w, n, n_block_rows)
+    depth = ring_depth(h, w, chunk, nnzb, a_bytes=0)
+    out_bytes = 2 if n_chunks == 1 else 4
+    return 2 * rows * chunk * 2 + depth * (w * chunk * 2 + h * w * out_bytes)
 
-    @pl.when(j == n_tiles - 1)
-    def _flush():
-        dv_ref[0] = acc_ref[...].astype(dv_ref.dtype)
+
+def _sddmm_stream_kernel(row_ref, col_ref, x_ref, y_hbm, o_hbm, y_buf,
+                         o_buf, sem, cursor, *, nnzb: int, h: int, w: int,
+                         col0: int, chunk: int, panel_rows: int, depth: int):
+    """One grid step = one panel p, whose X rows (``[R * h, chunk]``) the
+    BlockSpec pipeline holds in VMEM.  Its blocks, in stored order, wait
+    for their ``[w, chunk]`` Y slab in ring slot ``s % depth``, contract
+    it with their ``h`` rows of the panel in f32, and copy the ``[h, w]``
+    product out of the same slot of an output ring; the Y copies run
+    ``depth - 1`` blocks ahead, across panel edges.  The steps run in
+    grid order, so a panel's blocks start where the previous panel's
+    ended (``cursor``)."""
+    p = pl.program_id(0)
+    mask = depth - 1
+
+    def y_copy(col, slot):
+        return pltpu.make_async_copy(
+            y_hbm.at[pl.ds(col * w, w), pl.ds(col0, chunk)], y_buf.at[slot],
+            sem.at[0, slot])
+
+    def o_copy(s, slot):
+        return pltpu.make_async_copy(o_buf.at[slot], o_hbm.at[s],
+                                     sem.at[1, slot])
+
+    @pl.when(p == 0)
+    def _prime():
+        cursor[0] = 0
+
+        def body(s, carry):
+            y_copy(col_ref[s], s).start()
+            return carry
+        jax.lax.fori_loop(0, depth - 1, body, 0)
+
+    base = p * panel_rows
+
+    def in_panel(s):
+        row = row_ref[jnp.minimum(s, nnzb - 1)]
+        return jnp.logical_and(s < nnzb, row < base + panel_rows)
+
+    def body(s):
+        ahead = s + (depth - 1)
+
+        @pl.when(ahead < nnzb)
+        def _prefetch():
+            y_copy(col_ref[ahead], ahead & mask).start()
+
+        slot = s & mask
+        y_copy(0, slot).wait()
+        off = pl.multiple_of((row_ref[s] - base) * h, h)
+        # [h, chunk] x [w, chunk]^T -> [h, w]
+        prod = jax.lax.dot_general(
+            x_ref[pl.ds(off, h), :], y_buf[slot],
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(s >= depth)
+        def _slot_free():
+            o_copy(0, slot).wait()     # block s - depth has left the slot
+
+        o_buf[slot] = prod.astype(o_buf.dtype)
+        o_copy(s, slot).start()
+        return s + 1
+
+    cursor[0] = jax.lax.while_loop(in_panel, body, cursor[0])
+
+    @pl.when(p == pl.num_programs(0) - 1)
+    def _drain():            # depth <= nnzb: one copy in flight per slot
+        for slot in range(depth):
+            o_copy(0, slot).wait()
 
 
 def bcsr_sddmm(dc: jnp.ndarray, b: jnp.ndarray, row_ids: jnp.ndarray,
-               col_ids: jnp.ndarray, h: int, w: int, *, bn: int = 512,
-               out_dtype=None, interpret: bool = False):
+               col_ids: jnp.ndarray, h: int, w: int, *, out_dtype=None,
+               interpret: bool = False):
     """dVals[s] = dC[block row_ids[s]] @ B[block col_ids[s]]^T — the sparse
-    weight gradient, computed only at the stored blocks."""
+    weight gradient, computed only at the stored blocks.  Entries must be
+    sorted row-major; empty block-rows are allowed.  Each block is one f32
+    contraction over all of N, or a sum of f32 chunk products in chunk
+    order where the panel's rows of dC do not fit VMEM whole
+    (``sddmm_chunks``)."""
     M, N = dc.shape
     K, _ = b.shape
-    assert M % h == 0 and K % w == 0
-    bn = min(bn, N)
-    assert N % bn == 0
+    assert M % h == 0 and K % w == 0, (M, K, h, w)
     nnzb = row_ids.shape[0]
     out_dtype = out_dtype or dc.dtype
-    n_tiles = N // bn
-    grid = (nnzb, n_tiles)
+    if nnzb == 0:
+        return jnp.zeros((0, h, w), out_dtype)
+    nbr = M // h
+    r = panel_block_rows(h, nbr)
+    n_panels = -(-nbr // r)
+    chunk, n_chunks = sddmm_chunks(h, w, N, nbr, dc.dtype.itemsize,
+                                   b.dtype.itemsize)
+    if chunk * n_chunks > N:
+        pad = ((0, 0), (0, chunk * n_chunks - N))
+        dc, b = jnp.pad(dc, pad), jnp.pad(b, pad)
+    depth = ring_depth(h, w, chunk, nnzb, a_bytes=0,
+                       b_bytes=b.dtype.itemsize)
+    part_dtype = out_dtype if n_chunks == 1 else jnp.float32
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((h, bn),
-                         lambda s, j, row_ref, col_ref: (row_ref[s], j)),
-            pl.BlockSpec((w, bn),
-                         lambda s, j, row_ref, col_ref: (col_ref[s], j)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, h, w), lambda s, j, row_ref, col_ref: (s, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((h, w), jnp.float32)],
-    )
-    kernel = functools.partial(_sddmm_kernel, n_tiles=n_tiles)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nnzb, h, w), out_dtype),
-        interpret=interpret,
-        name="smat_sddmm_nnz_stream",
-    )(row_ids, col_ids, dc, b)
+    def call(k):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_panels,),
+            in_specs=[pl.BlockSpec((r * h, chunk), lambda p, *_: (p, k)),
+                      pl.BlockSpec(memory_space=pl.ANY)],   # B stays in HBM
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((depth, w, chunk), b.dtype),
+                            pltpu.VMEM((depth, h, w), part_dtype),
+                            pltpu.SemaphoreType.DMA((2, depth)),
+                            pltpu.SMEM((1,), jnp.int32)],
+        )
+        kernel = functools.partial(
+            _sddmm_stream_kernel, nnzb=nnzb, h=h, w=w, col0=k * chunk,
+            chunk=chunk, panel_rows=r, depth=depth)
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((nnzb, h, w), part_dtype),
+            # the rings and the cursor carry from one step into the next
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="smat_sddmm_nnz_stream",
+        )(row_ids, col_ids, dc, b)
+
+    out = call(0)
+    for k in range(1, n_chunks):
+        out = out + call(k)
+    return out.astype(out_dtype)
 
 
 # ========================================================== SDDMM (row-loop)

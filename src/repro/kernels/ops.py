@@ -492,8 +492,8 @@ def _sddmm_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
     pair sampled at the stored structure (X' = P X when the structure was
     prepared with a reorder; callers pass X in ORIGINAL row order).
 
-    Backends mirror the SpMM family: ``pallas`` streams the nonzero-block
-    list, ``row_loop`` runs the static (block-row x slot) schedule,
+    Backends mirror the SpMM family: ``pallas`` streams the stored blocks
+    by row panel, ``row_loop`` runs the static (block-row x slot) schedule,
     ``xla`` is the gather/einsum oracle, ``dense`` materializes the full
     X @ Y^T and gathers blocks.  Padding entries (``real_mask`` False) are
     zeroed — they are structural, not values."""
@@ -502,7 +502,10 @@ def _sddmm_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
         with jax.named_scope("smat.permute"):
             x = jnp.take(x, arrays.row_perm, axis=0)
     out_dtype = jnp.dtype(cfg.out_dtype) if cfg.out_dtype else x.dtype
-    bn = _clamp_bn(cfg.bn, max(x.shape[1], y.shape[1]))
+    # the streamed kernel contracts over all of N (or chunks of its own
+    # choosing), so it pads N to whole lanes only; the others tile N by bn
+    bn = 128 if cfg.backend == "pallas" else cfg.bn
+    bn = _clamp_bn(bn, max(x.shape[1], y.shape[1]))
     with jax.named_scope("smat.pad"):
         x_p, _ = _pad_b(x, h, bn)
         y_p, _ = _pad_b(y, w, bn)
@@ -513,7 +516,7 @@ def _sddmm_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
     with jax.named_scope(f"smat.kernel.{cfg.backend}"):
         if cfg.backend == "pallas":
             vals = pk.bcsr_sddmm(x_p, y_p, arrays.row_ids, arrays.col_ids,
-                                 h, w, bn=bn, out_dtype=out_dtype,
+                                 h, w, out_dtype=out_dtype,
                                  interpret=cfg.interpret)
         elif cfg.backend == "row_loop":
             if meta.max_bpr <= 0:
@@ -668,12 +671,19 @@ def resolve_backend(backend: str, bn: int, meta: SparseMeta,
     obs_trace.event("ops.dispatch", op=op, backend=backend, bn=bn, n=n,
                     nnzb=meta.nnzb, max_bpr=meta.max_bpr)
     obs_metrics.counter("ops.dispatch", op=op, backend=backend).inc()
-    if op == "spmm" and backend == "pallas":
-        # stored blocks one grid step (one row panel) of the forward
-        # kernel streams, on average
-        r = pk.panel_block_rows(meta.block[0], meta.n_block_rows)
-        obs_metrics.gauge("kernel.spmm.blocks_per_step", op=op).set(
+    if op in ("spmm", "sddmm") and backend == "pallas":
+        # stored blocks one grid step (one row panel) of the streamed
+        # kernel takes, on average
+        h, w = meta.block
+        r = pk.panel_block_rows(h, meta.n_block_rows)
+        obs_metrics.gauge(f"kernel.{op}.blocks_per_step", op=op).set(
             meta.nnzb / -(-meta.n_block_rows // r))
+        if op == "sddmm":
+            # launches over the contraction, N padded to whole lanes, on
+            # bf16 operands (1: the panel's X held whole)
+            _, n_chunks = pk.sddmm_chunks(h, w, -(-n // 128) * 128,
+                                          meta.n_block_rows)
+            obs_metrics.gauge("kernel.sddmm.k_chunks", op=op).set(n_chunks)
     return backend, bn
 
 

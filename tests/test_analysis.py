@@ -337,6 +337,31 @@ class TestVerifier:
         assert errs and any("VMEM" in e for e in errs)
         errs = vl.verify_launch(meta, "pallas", n=8192, bn=8192)
         assert errs and any("VMEM" in e for e in errs)
+        # the pallas SDDMM: X panel, Y ring and output ring; a long
+        # contraction is chunked, so it stays inside the budget
+        assert vl.verify_launch(meta, "pallas", n=512, op="sddmm") == []
+        errs = vl.verify_launch(meta, "pallas", n=512, op="sddmm",
+                                vmem_budget=1024)
+        assert errs and any("VMEM" in e for e in errs)
+        assert vl.verify_launch(meta, "pallas", n=65536, op="sddmm") == []
+
+    def test_sddmm_stream_vmem_estimate(self):
+        from repro.kernels import bcsr_spmm as pk
+        _, meta = _rand_case()
+        h, w = meta.block
+        # N = 100 is padded to one 128-lane chunk; bn plays no part
+        want = pk.sddmm_stream_vmem_bytes(h, w, 128, meta.n_block_rows,
+                                          meta.nnzb)
+        for bn in (128, 512):
+            assert vl.estimate_vmem_bytes(meta, "sddmm_stream", 100,
+                                          bn) == want
+        # HPCG 64^3 at N = 512: X panel 2 x 128 KB, 16 Y slabs of 128 KB,
+        # 16 output blocks of 4 KB
+        assert pk.sddmm_stream_vmem_bytes(16, 128, 512, 16384, 95760) == \
+            2 * 128 * 512 * 2 + 16 * (128 * 512 + 16 * 128) * 2
+        # the MoE's gate/up at D = 2,048: 8 slabs of 512 KB
+        assert pk.sddmm_stream_vmem_bytes(128, 128, 2048, 1600, 17600) == \
+            2 * 128 * 2048 * 2 + 8 * (128 * 2048 + 128 * 128) * 2
 
     def test_chunk_schedule_invariants(self):
         """Overlap schedules: every builder output passes; every corrupted

@@ -101,7 +101,18 @@ def _panel_edge_case(name: str):
         dense[3, 40] = 1.0
         dense[40, 500] = -2.0
         return bcsr_lib.from_dense(dense, (16, 16))
+    if name == "skewed_and_empty_rows":
+        # 5 panels of 8 block-rows: block-row 3 holds 32 blocks, block-row
+        # 20 one, the other 38 none (panels 1, 3 and 4 are empty)
+        dense = np.zeros((640, 512), np.float32)
+        dense[48:64] = rng.standard_normal((16, 512))
+        dense[325, 77] = 3.0
+        return bcsr_lib.from_dense(dense, (16, 16))
     raise ValueError(name)
+
+
+_TPU_INTERPRET = dict(dma_execution_mode="on_wait",
+                      uninitialized_memory="nan")
 
 
 @pytest.mark.parametrize("name", ["one_block_per_row",
@@ -120,8 +131,7 @@ def test_nnz_stream_panel_edges(name, dtype):
     ids = jnp.asarray(a.row_ids), jnp.asarray(a.col_ids)
     got = pk.bcsr_spmm_nnz_stream(
         vals, *ids, b, a.n_block_rows, bn=32,
-        interpret=pltpu.InterpretParams(dma_execution_mode="on_wait",
-                                        uninitialized_memory="nan"))
+        interpret=pltpu.InterpretParams(**_TPU_INTERPRET))
     want = ref.bcsr_spmm_ref(vals, *ids, b, a.n_block_rows)
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -171,8 +181,9 @@ def test_row_loop_handles_empty_and_skewed_rows():
                                atol=1e-4)
 
 
-@pytest.mark.parametrize("shape,block,density", SHAPES[:3])
+@pytest.mark.parametrize("shape,block,density", SHAPES[:3] + PANEL_SHAPES)
 def test_sddmm_matches_ref(shape, block, density):
+    # PANEL_SHAPES: panels of R = 8 block-rows (8 and 5 of 13) and R = 1
     a = _mk(shape, block, density)
     h, w = block
     rng = np.random.default_rng(6)
@@ -181,7 +192,7 @@ def test_sddmm_matches_ref(shape, block, density):
     b = rng.standard_normal((a.n_block_cols * w, 32)).astype(np.float32)
     got = pk.bcsr_sddmm(jnp.asarray(dc), jnp.asarray(b),
                         jnp.asarray(a.row_ids), jnp.asarray(a.col_ids),
-                        h, w, bn=32, interpret=True)
+                        h, w, interpret=True)
     want = ref.bcsr_sddmm_ref(jnp.asarray(dc), jnp.asarray(b),
                               jnp.asarray(a.row_ids), jnp.asarray(a.col_ids),
                               h, w)
@@ -264,7 +275,7 @@ def _sddmm_oracle(a, dc, b):
     return blocks[np.asarray(a.row_ids), np.asarray(a.col_ids)]
 
 
-@pytest.mark.parametrize("shape,block,density", SHAPES)
+@pytest.mark.parametrize("shape,block,density", SHAPES + PANEL_SHAPES)
 @pytest.mark.parametrize("n", [8, 64])
 def test_sddmm_matches_dense_masked_einsum(shape, block, density, n):
     a = _mk(shape, block, density)
@@ -277,7 +288,7 @@ def test_sddmm_matches_dense_masked_einsum(shape, block, density, n):
     want = _sddmm_oracle(a, dc, b)
     got = pk.bcsr_sddmm(jnp.asarray(dc), jnp.asarray(b),
                         jnp.asarray(a.row_ids), jnp.asarray(a.col_ids),
-                        h, w, bn=min(64, n), interpret=True)
+                        h, w, interpret=True)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
     got_ref = ref.bcsr_sddmm_ref(jnp.asarray(dc), jnp.asarray(b),
                                  jnp.asarray(a.row_ids),
@@ -343,13 +354,111 @@ def test_sddmm_dtypes_f32_accumulation(dtype):
     b = jnp.asarray(rng.standard_normal((128, 64)).astype(np.float32)
                     ).astype(dtype)
     got = pk.bcsr_sddmm(dc, b, jnp.asarray(a.row_ids),
-                        jnp.asarray(a.col_ids), 16, 16, bn=64,
+                        jnp.asarray(a.col_ids), 16, 16,
                         out_dtype=jnp.float32, interpret=True)
     assert got.dtype == jnp.float32
     want = _sddmm_oracle(a, np.asarray(dc, np.float32),
                          np.asarray(b, np.float32))
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("name", ["one_block_per_row",
+                                  "row_longer_than_ring",
+                                  "skewed_and_empty_rows"])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_sddmm_stream_panel_edges(name, dtype):
+    """The SDDMM's row panels under the TPU interpreter (each DMA lands
+    only when waited for, VMEM starts as NaN): a Y slab read before its
+    copy landed, or an output block overwritten in its ring slot before
+    its copy left, shows in the result."""
+    from jax.experimental.pallas import tpu as pltpu
+    a = _panel_edge_case(name)
+    h, w = a.block
+    rng = np.random.default_rng(18)
+    dc = jnp.asarray(rng.standard_normal(
+        (a.n_block_rows * h, 64)).astype(np.float32)).astype(dtype)
+    b = jnp.asarray(rng.standard_normal(
+        (a.n_block_cols * w, 64)).astype(np.float32)).astype(dtype)
+    ids = jnp.asarray(a.row_ids), jnp.asarray(a.col_ids)
+    got = pk.bcsr_sddmm(dc, b, *ids, h, w,
+                        interpret=pltpu.InterpretParams(**_TPU_INTERPRET))
+    want = ref.bcsr_sddmm_ref(dc, b, *ids, h, w)
+    tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype,n", [(np.float32, 2304),
+                                     (jnp.bfloat16, 4352)],
+                         ids=["float32", "bfloat16"])
+def test_sddmm_chunked_contraction(dtype, n):
+    """A contraction too wide for the panel's X to sit in VMEM whole is cut
+    into chunks (the last padded), each one launch over all panels; the
+    f32 chunk products are summed in chunk order."""
+    from jax.experimental.pallas import tpu as pltpu
+    a = _mk(*PANEL_SHAPES[0])                    # R = 8: 128-row panels
+    h, w = a.block
+    _, n_chunks = pk.sddmm_chunks(h, w, n, a.n_block_rows,
+                                  jnp.dtype(dtype).itemsize,
+                                  jnp.dtype(dtype).itemsize)
+    assert n_chunks == 2
+    rng = np.random.default_rng(19)
+    dc = jnp.asarray(rng.standard_normal(
+        (a.n_block_rows * h, n)).astype(np.float32)).astype(dtype)
+    b = jnp.asarray(rng.standard_normal(
+        (a.n_block_cols * w, n)).astype(np.float32)).astype(dtype)
+    ids = jnp.asarray(a.row_ids), jnp.asarray(a.col_ids)
+    got = pk.bcsr_sddmm(dc, b, *ids, h, w, out_dtype=jnp.float32,
+                        interpret=pltpu.InterpretParams(**_TPU_INTERPRET))
+    want = _sddmm_oracle(a, np.asarray(dc, np.float32),
+                         np.asarray(b, np.float32))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-3)
+
+
+def test_sddmm_moe_device_structure():
+    """The dropless MoE's device-built structure: 128-row blocks (R = 1),
+    two column blocks per expert, capacity block-rows masked out."""
+    from repro.models import moe
+    rng = np.random.default_rng(20)
+    n_experts, d_ff, d = 4, 256, 64
+    gate_idx = jnp.asarray(rng.integers(0, n_experts, (40, 2)), jnp.int32)
+    rows = moe.expert_rows(gate_idx, n_experts, moe.BLOCK_ROWS)
+    arrays, meta = moe.expert_structure(rows, n_experts, d_ff,
+                                        moe.expert_block(d_ff))
+    real = np.asarray(arrays.real_mask)
+    assert not real.all()                  # capacity block-rows are there
+    x = jnp.asarray(rng.standard_normal(
+        (meta.n_block_rows * moe.BLOCK_ROWS, d)).astype(np.float32))
+    y = jnp.asarray(rng.standard_normal(
+        (n_experts * d_ff, d)).astype(np.float32))
+    got = ops.sddmm(arrays, meta, x, y, backend="auto", interpret=True)
+    want = ref.bcsr_sddmm_ref(x, y, arrays.row_ids, arrays.col_ids,
+                              *meta.block)
+    want = np.asarray(want) * real[:, None, None]
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+    assert np.all(np.asarray(got)[~real] == 0.0)
+
+
+def test_sddmm_auto_pick_and_gauges_on_hpcg_meta():
+    """HPCG 64^3 in 16 x 128 blocks (the structure's own statistics): the
+    analytic ``auto`` pick of the SDDMM stays the Pallas kernel, one grid
+    step streams 95,760 / 2,048 blocks, and the X panel is held whole."""
+    from repro.obs import metrics
+    meta = ops.SparseMeta(shape=(262_144, 262_144), block=(16, 128),
+                          n_block_rows=16_384, n_block_cols=2_048,
+                          nnzb=95_760, nnzb_t=95_760, max_bpr=6,
+                          padding_ratio_pct=97, bpr_cv_pct=11)
+    assert ops.resolve_backend("auto", 512, meta, 512, op="sddmm")[0] == \
+        "pallas"
+    gauges = metrics.snapshot()["gauges"]
+    assert gauges["kernel.sddmm.blocks_per_step{op=sddmm}"] == 95_760 / 2_048
+    assert gauges["kernel.sddmm.k_chunks{op=sddmm}"] == 1
+    # a contraction of 32,768 does not fit whole: 8 chunks of 4,096
+    ops.resolve_backend("pallas", 512, meta, 32_768, op="sddmm")
+    assert metrics.snapshot()["gauges"][
+        "kernel.sddmm.k_chunks{op=sddmm}"] == 8
 
 
 def test_ops_sddmm_ragged_and_empty_rows():

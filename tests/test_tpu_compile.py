@@ -27,6 +27,10 @@ MAX_BPR = 4
 ATTN_LEN, ATTN_BAND, HEADS, HEAD_DIM = 32_768, 4096, 16, 128
 # HPCG 64^3 in 16 x 128 blocks: 262,144 rows, 95,760 stored blocks
 HPCG_BLOCK, HPCG_ROWS, HPCG_NNZB = (16, 128), 262_144, 95_760
+# DeepSeek-V2-Lite's gate/up SDDMM over 8 x 4,096 tokens: 1,600 block-rows
+# of 128 sorted token rows (top-6 of 64 experts), 11 blocks of 128 columns
+# of the experts' [64 * 1,408, 2,048] weights each
+MOE_ROWS, MOE_COLS, MOE_D, MOE_NNZB = 1_600 * 128, 64 * 1_408, 2_048, 17_600
 
 
 @pytest.fixture(scope="module")
@@ -90,8 +94,23 @@ def _kernel_cases(s):
             (vals, sched, sched, _sds(s, (nbr,), i32),
              _sds(s, (K, N), bf16))),
         "sddmm": (
-            functools.partial(pk.bcsr_sddmm, h=BLOCK[0], w=BLOCK[1], bn=N),
+            functools.partial(pk.bcsr_sddmm, h=BLOCK[0], w=BLOCK[1]),
             (_sds(s, (M, N), bf16), _sds(s, (K, N), bf16), ids, ids)),
+        "sddmm_hpcg_n512": (
+            functools.partial(pk.bcsr_sddmm, h=HPCG_BLOCK[0],
+                              w=HPCG_BLOCK[1]),
+            (_sds(s, (HPCG_ROWS, 512), bf16), _sds(s, (HPCG_ROWS, 512), bf16),
+             hpcg_ids, hpcg_ids)),
+        "sddmm_moe_d2048": (
+            functools.partial(pk.bcsr_sddmm, h=BLOCK[0], w=BLOCK[1]),
+            (_sds(s, (MOE_ROWS, MOE_D), bf16), _sds(s, (MOE_COLS, MOE_D), bf16),
+             _sds(s, (MOE_NNZB,), i32), _sds(s, (MOE_NNZB,), i32))),
+        # a contraction of 32,768 (the SpMM backward's dW over a long token
+        # axis): the panel's X does not fit whole, 8 chunks of 4,096
+        "sddmm_chunked_n32768": (
+            functools.partial(pk.bcsr_sddmm, h=BLOCK[0], w=BLOCK[1]),
+            (_sds(s, (M, 32_768), bf16), _sds(s, (K, 32_768), bf16),
+             ids, ids)),
         "sddmm_row_loop": (
             functools.partial(pk.bcsr_sddmm_row_loop, n_block_rows=nbr,
                               nnzb=NNZB, h=BLOCK[0], w=BLOCK[1], bn=N),
@@ -102,10 +121,15 @@ def _kernel_cases(s):
 @pytest.mark.parametrize("kernel", ["spmm_nnz_stream", "spmm_row_loop",
                                     "sddmm", "sddmm_row_loop",
                                     "spmm_nnz_stream_hpcg_bn128",
-                                    "spmm_nnz_stream_hpcg_bn512"])
+                                    "spmm_nnz_stream_hpcg_bn512",
+                                    "sddmm_hpcg_n512", "sddmm_moe_d2048",
+                                    "sddmm_chunked_n32768"])
 def test_bcsr_kernel_compiles_for_v5e(one_chip, kernel):
     fn, args = _kernel_cases(one_chip)[kernel]
-    assert "tpu_custom_call" in _compiled_text(fn, *args)
+    # one launch, or one per contraction chunk of the SDDMM
+    launches = 8 if kernel == "sddmm_chunked_n32768" else 1
+    text = _compiled_text(fn, *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == launches
 
 
 def test_fused_attention_compiles_for_v5e_at_32k(one_chip):
