@@ -4,9 +4,9 @@ block-size roofline table for the TPU target.
 Reports, per (block shape x N-tile): modeled T_e, arithmetic intensity,
 whether the block is MXU-aligned, and the VMEM working set of the BlockSpec
 tiling — the inputs to the §Perf kernel iteration.  Also cross-checks the
-nnz-stream and row-loop kernels against the oracle on a skewed matrix
-(the dc2 worst case) and reports the static-schedule waste factor the
-row-loop pays there (SMaT's documented weakness, fixed by nnz-streaming).
+nnz-stream kernel against the oracle on a skewed matrix (the dc2 worst
+case) and reports the waste factor a static (block-row x slot) schedule
+would pay there (SMaT's documented weakness, fixed by nnz-streaming).
 """
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ from repro.core import bcsr as bcsr_lib
 from repro.core import perf_model as pm
 from repro.core import topology
 from repro.kernels import bcsr_spmm as pk
-from repro.kernels import ops, ref
+from repro.kernels import ref
 
 VMEM_BYTES = 128 * 2 ** 20     # ~128 MiB usable VMEM on v5e-class core
 
@@ -39,7 +39,7 @@ def run():
                 f"AI={ai:.0f};vmem_kb={vmem/1024:.0f};"
                 f"mxu_aligned={aligned};fits_vmem={vmem < VMEM_BYTES}"))
 
-    # ---- dc2 worst case: static row-loop waste vs nnz-stream
+    # ---- dc2 worst case: static-schedule waste vs nnz-stream
     csr = topology.power_law(2048, 6.0, seed=3)
     a = bcsr_lib.from_scipy(csr, (16, 16)).ensure_nonempty_rows()
     bpr = a.blocks_per_row()
@@ -50,20 +50,14 @@ def run():
     got_stream = pk.bcsr_spmm_nnz_stream(
         jnp.asarray(a.vals), jnp.asarray(a.row_ids), jnp.asarray(a.col_ids),
         jnp.asarray(b), a.n_block_rows, bn=16, interpret=True)
-    fi, fc, rl_, mb = ops.make_row_loop_schedule(a)
-    got_loop = pk.bcsr_spmm_row_loop(
-        jnp.asarray(a.vals), fi, fc, rl_, jnp.asarray(b), a.n_block_rows,
-        bn=16, interpret=True)
     want = ref.bcsr_spmm_ref(jnp.asarray(a.vals), jnp.asarray(a.row_ids),
                              jnp.asarray(a.col_ids), jnp.asarray(b),
                              a.n_block_rows)
     ok_s = bool(np.allclose(np.asarray(got_stream), np.asarray(want),
                             atol=1e-4))
-    ok_l = bool(np.allclose(np.asarray(got_loop), np.asarray(want),
-                            atol=1e-4))
     rows.append(("kernel/dc2_static_schedule_waste", 0,
-                 f"row_loop_grid_steps/nnz_blocks={waste:.1f}x;"
-                 f"stream_correct={ok_s};loop_correct={ok_l};"
+                 f"static_grid_steps/nnz_blocks={waste:.1f}x;"
+                 f"stream_correct={ok_s};"
                  f"(nnz-stream pays 1.0x by construction)"))
     emit(rows)
     return rows

@@ -234,7 +234,7 @@ def library_phase(ck: Checks, sz: Sizes, interpret: bool = False) -> None:
             key, kb, kx, ky = jax.random.split(key, 4)
             # ---- SpMM: C = A @ B
             b = jax.random.normal(kb, (K, n), jnp.float32).astype(dtype)
-            outs = _both_schedules(lambda be: lambda ar, bb: ops.spmm(
+            outs = _run_pallas(lambda be: lambda ar, bb: ops.spmm(
                 ar, meta, bb, backend=be, interpret=interpret), arrs, b)
             errs = {be: ErrMax() for be in outs}
             b32 = b.astype(jnp.float32)
@@ -251,7 +251,7 @@ def library_phase(ck: Checks, sz: Sizes, interpret: bool = False) -> None:
             # ---- SDDMM: blocks of X Y^T at the stored structure
             x = jax.random.normal(kx, (M, n), jnp.float32).astype(dtype)
             y = jax.random.normal(ky, (K, n), jnp.float32).astype(dtype)
-            outs = _both_schedules(lambda be: lambda ar, xx, yy: ops.sddmm(
+            outs = _run_pallas(lambda be: lambda ar, xx, yy: ops.sddmm(
                 ar, meta, xx, yy, backend=be, out_dtype=jnp.float32,
                 interpret=interpret), arrs, x, y)
             xp = jnp.zeros((meta.n_block_rows * h, n), jnp.float32).at[:M].set(
@@ -277,14 +277,11 @@ def library_phase(ck: Checks, sz: Sizes, interpret: bool = False) -> None:
     _attention(ck, sz, interpret)
 
 
-def _both_schedules(make_fn, *args):
-    """``{backend: (output, compile seconds, n kernels)}`` for the two
-    Pallas schedules, nnz_stream (``pallas``) and ``row_loop``."""
-    outs = {}
-    for be in ("pallas", "row_loop"):
-        compiled, secs, nk = _compile(make_fn(be), *args)
-        outs[be] = (compiled(*args), secs, nk)
-    return outs
+def _run_pallas(make_fn, *args):
+    """``{"pallas": (output, compile seconds, n kernels)}`` for the
+    streamed Pallas kernel."""
+    compiled, secs, nk = _compile(make_fn("pallas"), *args)
+    return {"pallas": (compiled(*args), secs, nk)}
 
 
 def _report(ck, ph, name, err: ErrMax, tol, secs, n_kernels, interpret):
@@ -352,7 +349,7 @@ def server_phase(ck: Checks, sz: Sizes, cfg) -> None:
              for name, m in (("gate", meta_in), ("up", meta_in),
                              ("down", meta_out))}
     ck.check(ph, "sparse FFN picks", all(
-        be in ("pallas", "row_loop") for be, _ in picks.values()) and
+        be == "pallas" for be, _ in picks.values()) and
         not (spec.interpret and _on_tpu()),
         ", ".join(f"{k}: {be}/bn{bn}" for k, (be, bn) in picks.items()) +
         f" (backend={spec.backend!r}, interpret={spec.interpret})")

@@ -1,17 +1,15 @@
-"""Pre-launch static verification of schedules, grids, and VMEM budgets.
+"""Pre-launch static verification of schedules, metas and VMEM budgets.
 
 Everything that makes the TC-shaped kernels correct is decided BEFORE any
-device array exists: the (block-row x slot) static schedule, its sentinel
-padding convention, the grid derived from (meta, n, bn), and the VMEM
-working set.  This pass re-derives each of those symbolically — pure
-numpy on the host index structure — and checks the contracts the kernels
-assume:
+device array exists: the fused attention's (block-row x slot) static
+schedule and its sentinel padding convention, the meta's shapes and
+bounds, and the VMEM working set.  This pass checks each of those on the
+host index structure (numpy) against the contracts the kernels assume:
 
 * **coverage** — every live nnzb slot appears in the schedule exactly
-  once; sentinels (entry 0 for the spmm family, entry ``nnzb`` for
-  sddmm/attn) appear ONLY on padding slots;
+  once; the sentinel entry ``nnzb`` appears ONLY on padding slots;
 * **bounds** — every index the schedule can hand an index_map stays
-  inside the derived grid / operand shapes;
+  inside the operand shapes;
 * **shape** — block shapes divide the matrix dims or rag them by less
   than one block (``nbr == ceil(M/h)``, ``nbc == ceil(K/w)``);
 * **VMEM** — the per-cell working set (``repro.analysis.workspace``, the
@@ -19,7 +17,8 @@ assume:
   configurable budget when double-buffered.
 
 Entry points: ``verify_meta`` / ``verify_sharded_meta`` (invariants of a
-meta alone), ``verify_schedule`` (a concrete schedule against its meta),
+meta alone), ``verify_schedule`` (the schedule ``bcsr_attn.static_schedule``
+built, against its meta),
 ``assert_launch_ok`` (the opt-in ``REPRO_VERIFY_LAUNCH=1`` hook inside
 ``ops.resolve_backend``), ``verify_summary`` (the dict ``launch.dryrun``
 embeds), and ``run_verify`` (the CLI pass over the structure zoo).
@@ -31,25 +30,25 @@ embeds), and ``run_verify`` (the CLI pass over the structure zoo).
 >>> meta = ops.prepare_sparse_meta(a)
 >>> verify_meta(meta)
 []
->>> fi, fc = sddmm_row_loop_schedule_host(a.row_ids, a.col_ids,
-...                                       meta.n_block_rows, meta.max_bpr)
->>> verify_schedule("sddmm", fi, fc, a.row_ids, a.col_ids, meta)
+>>> from repro.kernels.bcsr_attn import static_schedule
+>>> fi, fc = static_schedule(a.row_ids, a.col_ids, meta.n_block_rows,
+...                          meta.max_bpr)
+>>> verify_schedule("attn", fi, fc, a.row_ids, a.col_ids, meta)
 []
 >>> bad = fi.copy(); bad[np.flatnonzero(fi != meta.nnzb)[0]] = meta.nnzb
->>> len(verify_schedule("sddmm", bad, fc, a.row_ids, a.col_ids, meta)) > 0
+>>> len(verify_schedule("attn", bad, fc, a.row_ids, a.col_ids, meta)) > 0
 True
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
 
 import numpy as np
 
 from repro.analysis import workspace
 from repro.analysis.report import Finding
 
-FAMILIES = ("spmm", "sddmm", "attn")
+FAMILIES = ("attn",)
 
 
 class LaunchError(ValueError):
@@ -57,67 +56,12 @@ class LaunchError(ValueError):
     launch — raised by ``assert_launch_ok`` before any kernel dispatch."""
 
 
-# ------------------------------------------------------- schedule mirrors
-def spmm_row_loop_schedule_host(row_ids, col_ids, n_block_rows: int,
-                                max_bpr: int):
-    """Host-numpy twin of ``ops._row_loop_schedule`` (and of the host
-    builder ``ops.make_row_loop_schedule``): per (block-row, slot) the
-    entry index and block-col, padding slots pointing at entry 0 / col 0,
-    plus the per-row live count ``row_len`` the kernel masks its loop
-    with."""
-    row_ids = np.asarray(row_ids, np.int64)
-    col_ids = np.asarray(col_ids, np.int64)
-    nnzb = row_ids.shape[0]
-    row_len = np.bincount(row_ids, minlength=n_block_rows)
-    rowptr = np.concatenate([[0], np.cumsum(row_len)])
-    slot = np.arange(nnzb) - rowptr[row_ids]
-    pos = row_ids * max_bpr + slot
-    flat_idx = np.zeros(n_block_rows * max_bpr, np.int32)
-    flat_col = np.zeros(n_block_rows * max_bpr, np.int32)
-    flat_idx[pos] = np.arange(nnzb, dtype=np.int32)
-    flat_col[pos] = col_ids
-    return flat_idx, flat_col, row_len.astype(np.int32)
-
-
-def sddmm_row_loop_schedule_host(row_ids, col_ids, n_block_rows: int,
-                                 max_bpr: int):
-    """Host-numpy twin of ``ops._sddmm_row_loop_schedule`` AND of the
-    fused-attention schedule (``models.attention._fused_inputs`` builds
-    the identical arrays): padding slots point at the sentinel entry
-    ``nnzb`` instead of entry 0."""
-    row_ids = np.asarray(row_ids, np.int64)
-    col_ids = np.asarray(col_ids, np.int64)
-    nnzb = row_ids.shape[0]
-    row_len = np.bincount(row_ids, minlength=n_block_rows)
-    rowptr = np.concatenate([[0], np.cumsum(row_len)])
-    slot = np.arange(nnzb) - rowptr[row_ids]
-    pos = row_ids * max_bpr + slot
-    flat_idx = np.full(n_block_rows * max_bpr, nnzb, np.int32)
-    flat_col = np.zeros(n_block_rows * max_bpr, np.int32)
-    flat_idx[pos] = np.arange(nnzb, dtype=np.int32)
-    flat_col[pos] = col_ids
-    return flat_idx, flat_col
-
-
-def build_schedule(family: str, row_ids, col_ids, meta):
-    """(flat_idx, flat_col, row_len|None) for ``family`` from the sorted
-    entry list — the schedule the kernels would actually launch with."""
-    if family not in FAMILIES:
-        raise ValueError(f"unknown family {family!r}; want one of {FAMILIES}")
-    if family == "spmm":
-        return spmm_row_loop_schedule_host(
-            row_ids, col_ids, meta.n_block_rows, meta.max_bpr)
-    fi, fc = sddmm_row_loop_schedule_host(
-        row_ids, col_ids, meta.n_block_rows, meta.max_bpr)
-    return fi, fc, None
-
-
 # ------------------------------------------------------- meta invariants
 def verify_meta(meta) -> list:
     """Structural invariants of one ``SparseMeta`` — no arrays involved.
 
     Dims-only specs metas (``max_bpr == 0``) are legal: they carry shape
-    budgets, not a realized structure, and the row_loop family refuses
+    budgets, not a realized structure, and the fused attention refuses
     them separately.  Shard-local metas (``n_shards > 1``) may contain
     duplicate (row, col) slots (padding), so the distinct-entries bound
     ``nnzb <= nbr * nbc`` applies only to whole-matrix metas."""
@@ -213,15 +157,14 @@ def verify_sharded_meta(smeta) -> list:
 
 # ---------------------------------------------------- schedule verification
 def verify_schedule(family: str, flat_idx, flat_col, row_ids, col_ids,
-                    meta, row_len=None) -> list:
+                    meta) -> list:
     """Check one realized (block-row x slot) schedule against its meta.
 
-    ``family`` fixes the sentinel convention: ``"spmm"`` pads with entry 0
-    and needs ``row_len`` (the kernel's loop mask); ``"sddmm"``/``"attn"``
-    pad with the sentinel entry index ``nnzb``.  Returns a list of error
-    strings — empty means the schedule covers every live slot exactly
-    once, sentinels sit only on padding, every index is in bounds, and
-    the (row, col) bookkeeping is self-consistent."""
+    Padding slots hold the sentinel entry index ``nnzb`` and block-col 0.
+    Returns a list of error strings — empty means the schedule covers
+    every live slot exactly once, sentinels sit only on padding, every
+    index is in bounds, and the (row, col) bookkeeping is
+    self-consistent."""
     if family not in FAMILIES:
         raise ValueError(f"unknown family {family!r}; want one of {FAMILIES}")
     errs = []
@@ -255,49 +198,23 @@ def verify_schedule(family: str, flat_idx, flat_col, row_ids, col_ids,
     if counts.max(initial=0) > max_bpr:
         return [f"{family}: a block-row holds {int(counts.max())} entries "
                 f"> max_bpr={max_bpr} — schedule cannot represent it"]
-    slots = np.arange(want_len) % max_bpr
     seg_row = np.arange(want_len) // max_bpr
-    if family == "spmm":
-        if row_len is None:
-            return [f"{family}: row_len is required (the kernel's loop "
-                    "bound) for the spmm family"]
-        row_len = np.asarray(row_len, np.int64)
-        if row_len.shape[0] != nbr:
-            return [f"{family}: row_len length {row_len.shape[0]} != "
-                    f"n_block_rows={nbr}"]
-        if not np.array_equal(row_len, counts):
-            bad = int(np.flatnonzero(row_len != counts)[0])
-            errs.append(
-                f"{family}: row_len[{bad}]={int(row_len[bad])} != true "
-                f"entry count {int(counts[bad])} — the loop mask drops or "
-                "double-visits slots")
-        live = slots < row_len[seg_row]
-        # in-bounds: every slot (live or padding) indexes a real entry
-        if flat_idx.min() < 0 or flat_idx.max() >= max(nnzb, 1):
-            errs.append(f"{family}: flat_idx outside [0, nnzb={nnzb}) — "
-                        "spmm padding must reuse entry 0, not a sentinel")
-        pad_bad = np.flatnonzero(~live & ((flat_idx != 0) | (flat_col != 0)))
-        if pad_bad.size:
-            errs.append(f"{family}: {pad_bad.size} padding slot(s) (first "
-                        f"at {int(pad_bad[0])}) not pointing at entry 0 / "
-                        "col 0")
-    else:
-        live = flat_idx != nnzb
-        if flat_idx.min() < 0 or flat_idx.max() > nnzb:
-            errs.append(f"{family}: flat_idx outside [0, nnzb={nnzb}] "
-                        "(sentinel row is index nnzb)")
-        live_counts = np.bincount(seg_row[live], minlength=nbr)
-        if not np.array_equal(live_counts, counts):
-            bad = int(np.flatnonzero(live_counts != counts)[0])
-            errs.append(
-                f"{family}: block-row {bad} schedules "
-                f"{int(live_counts[bad])} live slot(s) but owns "
-                f"{int(counts[bad])} entries — sentinel on a live block "
-                "or a dropped slot")
-        pad_bad = np.flatnonzero(~live & (flat_col != 0))
-        if pad_bad.size:
-            errs.append(f"{family}: {pad_bad.size} sentinel slot(s) with "
-                        "nonzero flat_col (must DMA block-col 0)")
+    live = flat_idx != nnzb
+    if flat_idx.min() < 0 or flat_idx.max() > nnzb:
+        errs.append(f"{family}: flat_idx outside [0, nnzb={nnzb}] "
+                    "(sentinel row is index nnzb)")
+    live_counts = np.bincount(seg_row[live], minlength=nbr)
+    if not np.array_equal(live_counts, counts):
+        bad = int(np.flatnonzero(live_counts != counts)[0])
+        errs.append(
+            f"{family}: block-row {bad} schedules "
+            f"{int(live_counts[bad])} live slot(s) but owns "
+            f"{int(counts[bad])} entries — sentinel on a live block "
+            "or a dropped slot")
+    pad_bad = np.flatnonzero(~live & (flat_col != 0))
+    if pad_bad.size:
+        errs.append(f"{family}: {pad_bad.size} sentinel slot(s) with "
+                    "nonzero flat_col (must DMA block-col 0)")
     if errs:
         return errs
 
@@ -321,33 +238,17 @@ def verify_schedule(family: str, flat_idx, flat_col, row_ids, col_ids,
     return errs
 
 
-# ------------------------------------------------------ grid + VMEM checks
-def derive_grid(meta, family: str, n: int, bn: int = 512):
-    """The Pallas grid the row_loop/fused kernels launch with — the bound
-    every schedule index must stay inside."""
-    from repro.kernels import ops
-    bn_eff = ops._clamp_bn(bn, n)
-    n_tiles = -(-n // bn_eff)
-    nbr, max_bpr = meta.n_block_rows, meta.max_bpr
-    if family == "spmm":
-        return (nbr, n_tiles, max_bpr)
-    if family == "sddmm":
-        return (nbr, max_bpr, n_tiles)
-    if family == "attn":
-        return (1, nbr, 3, max_bpr)
-    raise ValueError(f"unknown family {family!r}")
-
-
+# ------------------------------------------------------------ VMEM checks
 def estimate_vmem_bytes(meta, family: str, n: int, bn: int = 512) -> int:
     """Working-set estimate for one grid cell (``n`` is N for the
-    spmm/sddmm families, head_dim for attn).  ``stream`` is the ``pallas``
+    streamed kernels, head_dim for attn).  ``stream`` is the ``pallas``
     SpMM kernel: its f32 accumulator, output tile and DMA ring
     (``bcsr_spmm.nnz_stream_vmem_bytes``); ``sddmm_stream`` the ``pallas``
     SDDMM kernel: the panel's X, the ring of Y slabs and the ring of output
     blocks over N padded to whole lanes (``bcsr_spmm.
-    sddmm_stream_vmem_bytes``; ``bn`` plays no part); the others are the
-    double-buffered cells of the shared ``repro.analysis.workspace``
-    formulas."""
+    sddmm_stream_vmem_bytes``; ``bn`` plays no part); ``attn`` the fused
+    attention's running state plus one double-buffered cell of the shared
+    ``repro.analysis.workspace`` formulas."""
     from repro.kernels import bcsr_spmm, ops
     bn_eff = ops._clamp_bn(bn, n)
     h, w = meta.block
@@ -360,41 +261,29 @@ def estimate_vmem_bytes(meta, family: str, n: int, bn: int = 512) -> int:
     if family == "attn":
         return (workspace.attn_fused_state_bytes(meta.block, n)
                 + workspace.spmm_cell_bytes(meta.block, bn_eff))
-    return workspace.spmm_cell_bytes(meta.block, bn_eff) * 2
+    raise ValueError(f"unknown VMEM family {family!r}")
 
 
-def _family_for(backend: str, op: str) -> Optional[str]:
-    """Which static-schedule family (if any) a resolved backend launches.
-    ``None`` = no row_loop-style schedule (nnz_stream / xla / dense)."""
-    if op == "attn":
-        return "attn" if backend in ("fused", "row_loop") else None
-    if backend == "row_loop":
-        return op if op in ("spmm", "sddmm") else "spmm"
-    return None
+# the VMEM family of each (backend, op) launch that runs a Pallas kernel
+_VMEM_FAMILY = {("pallas", "spmm"): "stream",
+                ("pallas", "sddmm"): "sddmm_stream",
+                ("fused", "attn"): "attn"}
 
 
 def verify_launch(meta, backend: str, *, n: int, bn: int = 512,
                   op: str = "spmm",
                   vmem_budget: int = workspace.DEFAULT_VMEM_BUDGET) -> list:
     """All static checks for one resolved (meta, backend, n, bn, op)
-    launch: meta invariants, schedule feasibility for the backend's
-    family, and the VMEM budget.  Returns error strings (empty = ok)."""
+    launch: meta invariants (which hold the fused attention's schedule
+    capacity, ``nnzb <= n_block_rows * max_bpr``), a schedule bound for
+    the fused attention, and the VMEM budget of a Pallas kernel.  Returns
+    error strings (empty = ok)."""
     errs = list(verify_meta(meta))
-    family = _family_for(backend, op)
-    if family is not None and meta.max_bpr <= 0:
-        errs.append(f"backend {backend!r} (family {family}) needs "
-                    "meta.max_bpr > 0; this is a dims-only meta")
-    if family is not None and meta.max_bpr > 0:
-        sched_len = meta.n_block_rows * meta.max_bpr
-        if sched_len < meta.nnzb:
-            errs.append(f"schedule length {sched_len} cannot cover "
-                        f"nnzb={meta.nnzb}")
-        grid = derive_grid(meta, family, n, bn)
-        if any(g <= 0 for g in grid):
-            errs.append(f"degenerate grid {grid} for family {family}")
-    if backend in ("pallas", "row_loop", "fused"):
-        vmem_family = family or {"spmm": "stream",
-                                 "sddmm": "sddmm_stream"}.get(op, "spmm")
+    if (backend, op) == ("fused", "attn") and meta.max_bpr <= 0:
+        errs.append("the fused attention needs meta.max_bpr > 0; this is a "
+                    "dims-only meta")
+    vmem_family = _VMEM_FAMILY.get((backend, op))
+    if vmem_family is not None:
         need = estimate_vmem_bytes(meta, vmem_family, n, bn)
         if need > vmem_budget:
             errs.append(
@@ -521,8 +410,8 @@ def verify_summary(meta, n: int, op: str = "spmm") -> dict:
 # ------------------------------------------------------------ structure zoo
 @dataclasses.dataclass
 class ZooCase:
-    """One realized structure: the meta plus the sorted host entry list
-    the schedules are built from, and which families apply to it."""
+    """One realized structure: the meta plus its sorted host entry list,
+    and the ops (``spmm`` | ``sddmm`` | ``attn``) it is verified under."""
     name: str
     meta: object
     row_ids: np.ndarray
@@ -588,10 +477,11 @@ def structure_zoo():
 
 def run_verify(vmem_budget: int = workspace.DEFAULT_VMEM_BUDGET,
                n_values=(64, 512)) -> list:
-    """The CLI pass: prove every zoo meta's invariants and every
-    applicable schedule's contracts, plus grid/VMEM feasibility at a few
-    N values.  Returns ``Finding``s (empty = the tree's structural
-    contracts hold)."""
+    """The CLI pass: prove every zoo meta's invariants, the fused
+    attention's schedule on every attention mask, and the Pallas kernels'
+    VMEM budgets at a few N values.  Returns ``Finding``s (empty = the
+    tree's structural contracts hold)."""
+    from repro.kernels.bcsr_attn import static_schedule
     findings = []
 
     def emit(case, msgs):
@@ -607,16 +497,15 @@ def run_verify(vmem_budget: int = workspace.DEFAULT_VMEM_BUDGET,
             emit(case, verify_meta(case.meta))
             metas = [(case.meta, case.row_ids, case.col_ids)]
         for m, rows, cols in metas:
-            for family in case.families:
-                sched = build_schedule(family, rows, cols, m)
-                emit(case, verify_schedule(family, sched[0], sched[1],
-                                           rows, cols, m, row_len=sched[2]))
-                backend = "fused" if family == "attn" else "row_loop"
-                op = family if family != "attn" else "attn"
+            for op in case.families:
+                if op == "attn":
+                    fi, fc = static_schedule(rows, cols, m.n_block_rows,
+                                             m.max_bpr)
+                    emit(case, verify_schedule(op, fi, fc, rows, cols, m))
+                backend = "fused" if op == "attn" else "pallas"
                 for n in n_values:
-                    emit(case, [e for e in verify_launch(
-                        m, backend, n=n, op=op, vmem_budget=vmem_budget)
-                        if e])
+                    emit(case, verify_launch(m, backend, n=n, op=op,
+                                             vmem_budget=vmem_budget))
         # overlap chunk schedules: the pipelined dispatch is only
         # bit-identical if every (n, n_chunks) schedule partitions the
         # panel exactly and each chunk's working set stays within VMEM

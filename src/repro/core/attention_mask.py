@@ -122,7 +122,7 @@ class AttnSparsitySpec:
     pages whose softmax contribution is exactly zero."""
     mask: AttnMaskSpec = dataclasses.field(default_factory=blockwise_causal)
     block: Tuple[int, int] = (16, 16)
-    backend: str = "auto"   # pallas | row_loop | xla | dense | auto | fused
+    backend: str = "auto"   # pallas | xla | dense | auto | fused
     bn: int = 512
     interpret: bool = False
     shards: int = 0                 # >0: row-shard the score structure

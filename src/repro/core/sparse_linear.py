@@ -90,7 +90,7 @@ class SparsitySpec:
     """
     density: float = 0.1            # fraction of nonzero blocks
     block: Tuple[int, int] = (128, 128)
-    backend: str = "pallas"         # pallas | row_loop | xla | dense | auto
+    backend: str = "pallas"         # pallas | xla | dense | auto
     bn: int = 512
     interpret: bool = False
     tune_n: int = 0                 # measured sweep at init for this N
@@ -208,9 +208,9 @@ def sparse_linear_meta(seed: int, in_dim: int, out_dim: int,
     the full per-shard ``ShardedMeta`` when ``spec.shards > 0``) is a pure
     static function of ``(seed, dims, spec)``.  The model path uses this
     at trace time (``models.layers.mlp``) so ``backend="auto"`` resolves
-    heterogeneous per-shard kernel picks and ``row_loop`` sizes its static
-    schedule from the permuted structure — identically to dispatching on
-    the meta ``init_sparse_linear`` returned."""
+    heterogeneous per-shard kernel picks from the permuted structure —
+    identically to dispatching on the meta ``init_sparse_linear``
+    returned."""
     a = _pattern_for(seed, in_dim, out_dim, spec)
     if is_sharded(spec):
         from repro.launch import dist_spmm  # local: layering
@@ -247,10 +247,10 @@ def merge_sparse_metas(metas):
     but draw different structures; the scanned body traces once, so it
     must dispatch on a single static meta.  The merge keeps the shared
     static fields and takes the elementwise MAX of the stats
-    (``max_bpr`` — so a ``row_loop`` schedule covers every layer —
-    padding, and skew); for ``ShardedMeta`` the per-shard metas merge
-    shard-wise, preserving cross-shard heterogeneity.  Raises if the
-    metas' static structure differs (different specs were mixed)."""
+    (``max_bpr``, padding and skew); for ``ShardedMeta`` the per-shard
+    metas merge shard-wise, preserving cross-shard heterogeneity.  Raises
+    if the metas' static structure differs (different specs were
+    mixed)."""
     metas = list(metas)
     if not metas:
         raise ValueError("merge_sparse_metas needs at least one meta")
@@ -354,9 +354,9 @@ def sparse_linear_specs(in_dim: int, out_dim: int, spec: SparsitySpec,
     — real per-shard stats, real post-reorder ``max_bpr``), equal to what
     ``init_sparse_linear(seed, ...)`` returns; the params stay
     ShapeDtypeStructs either way.  With ``seed=None`` (pure dims-only
-    mode, no host work at all) the stats are zero: ``auto`` dispatch falls
-    back to the streaming kernel and ``row_loop`` raises — fine for
-    shape/sharding proofs, wrong for kernel-choice questions."""
+    mode, no host work at all) the stats are zero, so ``auto`` dispatch
+    sees dims only — fine for shape/sharding proofs, wrong for
+    kernel-choice questions."""
     if seed is not None:
         params, _ = sparse_linear_specs(in_dim, out_dim, spec, dtype)
         return params, sparse_linear_meta(seed, in_dim, out_dim, spec)

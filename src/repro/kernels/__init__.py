@@ -1,7 +1,6 @@
 """SpMM/SDDMM kernel package.
 
-  bcsr_spmm — Pallas TPU kernels (nnz_stream / row_loop / sddmm /
-              sddmm_row_loop)
+  bcsr_spmm — Pallas TPU kernels (nnz_stream SpMM / streamed sddmm)
   bcsr_attn — fused one-kernel block-sparse attention (flash-style
               single launch over the static schedule; bit-for-bit equal
               to the composed SDDMM -> softmax -> SpMM triple in f32)

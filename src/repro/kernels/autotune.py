@@ -4,7 +4,7 @@ SMaT's headline speedups come from matching the kernel schedule and tile
 parameters to the matrix's block structure; a single hardcoded
 (nnz_stream, bn=512) leaves that on the table.  This module provides:
 
-  * a **registry** of SpMM kernel variants (nnz_stream / row_loop / xla
+  * a **registry** of SpMM kernel variants (nnz_stream / xla
     gather-scatter / dense fallback), each with its tunable ``bn``
     candidates and dispatch constraints;
   * a **fingerprint** of a BCSR operand's structure (nnzb, padding ratio,
@@ -58,7 +58,7 @@ class KernelVariant:
     ``ops.SpmmConfig.backend`` string the variant lowers to; ``model_time``
     maps (meta, n, bn) -> predicted seconds (paper Eq. 1 terms from
     ``core.perf_model``); ``supported`` gates dispatch on static metadata
-    (e.g. row_loop needs a known max_bpr).
+    (e.g. attn_fused needs a known max_bpr).
     """
     name: str
     backend: str
@@ -102,14 +102,6 @@ def _t_nnz_stream(meta: ops.SparseMeta, n: int, bn: int) -> float:
     return pm.spmm_model_time(meta.nnzb * _n_tiles(n, bn), h, w, bn)
 
 
-def _t_row_loop(meta: ops.SparseMeta, n: int, bn: int) -> float:
-    # static schedule pays max_bpr slots on EVERY block-row (SMaT's dc2
-    # worst case); padding DMAs still move bytes.
-    h, w = meta.block
-    n_e = meta.n_block_rows * max(meta.max_bpr, 1) * _n_tiles(n, bn)
-    return pm.spmm_model_time(n_e, h, w, bn)
-
-
 def _t_xla(meta: ops.SparseMeta, n: int, bn: int) -> float:
     # gather + einsum + segment_sum: streams every stored element with
     # blocked (coalesced) access — modeled as CSR traffic at low overhead.
@@ -126,11 +118,6 @@ register_variant(KernelVariant(
     name="nnz_stream", backend="pallas", bn_candidates=(128, 256, 512, 1024),
     model_time=_t_nnz_stream,
     description="nonzero-block-streamed Pallas kernel (skew-immune)"))
-register_variant(KernelVariant(
-    name="row_loop", backend="row_loop", bn_candidates=(128, 256, 512),
-    model_time=_t_row_loop,
-    supported=lambda meta: meta.max_bpr > 0,
-    description="paper-faithful static 2D schedule (loop to max_bpr)"))
 register_variant(KernelVariant(
     name="xla", backend="xla", bn_candidates=(512,),
     model_time=_t_xla,
@@ -158,14 +145,6 @@ def _t_sddmm_stream(meta: ops.SparseMeta, n: int, bn: int) -> float:
             + n_chunks * _T_INIT)
 
 
-def _t_sddmm_row_loop(meta: ops.SparseMeta, n: int, bn: int) -> float:
-    # static schedule: every (block-row, slot) pair pays its product, even
-    # the padding slots that land in the sentinel output block
-    h, w = meta.block
-    n_e = meta.n_block_rows * max(meta.max_bpr, 1) * _n_tiles(n, bn)
-    return pm.spmm_model_time(n_e, h, w, bn)
-
-
 def _t_sddmm_xla(meta: ops.SparseMeta, n: int, bn: int) -> float:
     h, w = meta.block
     return pm.csr_spmm_time(meta.nnzb * h * w, n, gather_overhead=2.0)
@@ -183,11 +162,6 @@ register_variant(KernelVariant(
     bn_candidates=(512,), model_time=_t_sddmm_stream,
     description="row-panel-streamed Pallas SDDMM (skew-immune)"))
 register_variant(KernelVariant(
-    name="sddmm_row_loop", backend="row_loop", op="sddmm",
-    bn_candidates=(128, 256, 512), model_time=_t_sddmm_row_loop,
-    supported=lambda meta: meta.max_bpr > 0,
-    description="paper-faithful static (block-row x slot) SDDMM schedule"))
-register_variant(KernelVariant(
     name="sddmm_xla", backend="xla", op="sddmm",
     bn_candidates=(512,), model_time=_t_sddmm_xla,
     description="pure-jnp gather/einsum SDDMM (shardable oracle path)"))
@@ -204,7 +178,7 @@ register_variant(KernelVariant(
 # ``models.attention.resolve_attn_impl``, not by ``ops.SpmmConfig``.
 def _t_attn_fused(meta: ops.SparseMeta, n: int, bn: int) -> float:
     # one launch, three passes (max / denom / accumulate) over the static
-    # (block-row x slot) schedule — row_loop-style waste on short rows,
+    # (block-row x slot) schedule — padding slots waste work on short rows,
     # but zero scores/probs HBM traffic between phases
     h, w = meta.block
     n_e = meta.n_block_rows * max(meta.max_bpr, 1) * 3
@@ -251,10 +225,11 @@ class Fingerprint:
     has its own stats AND a different execution context (its N-tile shares
     the device with the other shards), so per-shard picks must not alias
     the unsharded twin's entries.  ``max_bpr`` (v4) carries the
-    ``row_loop`` schedule bound EXACTLY (not bucketed): reordering shrinks
-    it, the static schedule length is ``n_block_rows * max_bpr``, and two
-    structures whose other stats coincide but whose schedule bounds differ
-    must never share a cached ``row_loop`` decision.  ``op`` (v5) names
+    fused attention's schedule bound EXACTLY (not bucketed): reordering
+    shrinks it, the fused kernel's static (block-row x slot) grid is
+    ``n_block_rows * max_bpr`` long, and two structures whose other stats
+    coincide but whose schedule bounds differ must never share a cached
+    ``attn_fused`` decision.  ``op`` (v5) names
     the compute family: ``ops.spmm`` and ``ops.sddmm`` dispatch over the
     SAME structures with different optimal schedules (SDDMM contracts
     over N instead of streaming it), so their picks
@@ -280,7 +255,7 @@ class Fingerprint:
     n_bucket: int        # next pow2 of N
     reorder: str = "identity"
     n_shards: int = 1    # shard count of the partitioned operand (1 = whole)
-    max_bpr: int = 0     # row_loop schedule bound (0 = unknown/dims-only)
+    max_bpr: int = 0     # fused attention's schedule bound (0 = unknown)
     op: str = "spmm"     # compute family (spmm | sddmm | attn)
     n_chunks: int = 1    # B-panel overlap chunks (shard-count key axis)
 

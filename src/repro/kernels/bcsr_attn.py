@@ -7,12 +7,11 @@ The composed path (PR 5) is three dispatches per head:
     probs  = block_softmax(scores)   # materializes [nnzb, h, w] again
     ctx    = ops.spmm(probs, V)
 
-``bcsr_attn_fused`` walks the SAME static (block-row x slot) schedule the
-``row_loop`` SDDMM uses (``ops._sddmm_row_loop_schedule``: padding slots
-point at a sentinel entry) but never writes a score or prob block to HBM:
-each grid cell recomputes its Q K^T block on the fly and folds it into
-per-query-block running state held in VMEM scratch — O(L * d) memory and
-one kernel launch instead of three.
+``bcsr_attn_fused`` walks a static (block-row x slot) schedule
+(``static_schedule``: padding slots point at a sentinel entry) and never
+writes a score or prob block to HBM: each grid cell recomputes its Q K^T
+block on the fly and folds it into per-query-block running state held in
+VMEM scratch — O(L * d) memory and one kernel launch instead of three.
 
 **Bit-for-bit contract.**  The fused forward is pinned bitwise-equal (f32)
 to the composed SDDMM -> ``block_softmax`` -> SpMM path.  A classic
@@ -50,11 +49,33 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.attention_mask import NEG_INF
 from repro.kernels.ops import _clamp_bn
+
+
+def static_schedule(row_ids, col_ids, n_block_rows: int, max_bpr: int):
+    """``(flat_idx, flat_col)``, the kernel's static schedule, from the
+    row-major sorted entry list (host numpy): per (block-row, slot) the
+    entry index and block-col, ``n_block_rows * max_bpr`` slots in all.
+    Padding slots hold the sentinel entry ``nnzb`` and block-col 0.
+
+    >>> static_schedule([0, 1, 1], [0, 0, 1], 2, 2)
+    (array([0, 3, 1, 2], dtype=int32), array([0, 0, 0, 1], dtype=int32))
+    """
+    row_ids = np.asarray(row_ids, np.int64)
+    nnzb = row_ids.shape[0]
+    counts = np.bincount(row_ids, minlength=n_block_rows)
+    rowptr = np.concatenate([[0], np.cumsum(counts)])
+    pos = row_ids * max_bpr + np.arange(nnzb) - rowptr[row_ids]
+    flat_idx = np.full(n_block_rows * max_bpr, nnzb, np.int32)
+    flat_col = np.zeros(n_block_rows * max_bpr, np.int32)
+    flat_idx[pos] = np.arange(nnzb, dtype=np.int32)
+    flat_col[pos] = col_ids
+    return flat_idx, flat_col
 
 
 def _attn_fused_kernel(idx_ref, col_ref, q_ref, k_ref, v_ref, em_ref, o_ref,
@@ -144,7 +165,7 @@ def bcsr_attn_fused(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               row-major.  A zero sentinel block is appended internally.
     flat_idx  ``[nbr * max_bpr]`` entry index per (block-row, slot);
               padding slots hold the sentinel index ``nnzb``
-              (``ops._sddmm_row_loop_schedule`` layout).
+              (the ``static_schedule`` layout).
     flat_col  ``[nbr * max_bpr]`` block-col per (block-row, slot).
     scale     applied to the scores before the optional ``cap`` tanh
               soft-clip, exactly like ``models.attention.block_softmax``.

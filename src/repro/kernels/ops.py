@@ -10,9 +10,6 @@ metadata is static.
 Backends:
   * ``pallas``   — the nnz-streamed TPU kernel (``interpret=True`` on CPU).
                    ``nnz_stream`` is accepted as an alias.
-  * ``row_loop`` — the paper-faithful static-schedule TPU kernel (one grid
-                   cell per block-row x N-tile, masked loop to max_bpr).
-                   Requires ``meta.max_bpr > 0`` (set by ``prepare_sparse``).
   * ``xla``      — pure-jnp reference path (shardable; used by the
                    512-device dry-run and as the CI oracle).
   * ``dense``    — materialize the padded dense matrix and ``jnp.dot`` (the
@@ -63,13 +60,13 @@ class SparseMeta:
     """Static (hashable) metadata of a sparse operand.
 
     The trailing stats fields feed the autotuner's fingerprint (and the
-    ``row_loop`` backend, which needs ``max_bpr`` to size its static
-    schedule).  They default to "unknown" so hand-built metas (e.g. the
-    dry-run's dims-only ``sparse_linear_specs``) keep working — the
-    autotuner simply won't propose ``row_loop`` for those.  Because the
-    whole dataclass is hashable, a meta is safe to close over inside jit
-    traces and to ride through scan-stacked model layers as STATIC aux
-    data (never as a pytree leaf) — the contract
+    fused attention kernel, which sizes its static (block-row x slot) grid
+    by ``max_bpr``).  They default to "unknown" so hand-built metas (e.g.
+    the dry-run's dims-only ``sparse_linear_specs``) keep working — the
+    autotuner simply won't propose the fused attention for those.
+    Because the whole dataclass is hashable, a meta is safe to close over
+    inside jit traces and to ride through scan-stacked model layers as
+    STATIC aux data (never as a pytree leaf) — the contract
     ``docs/ARCHITECTURE.md`` spells out.
     """
     shape: Tuple[int, int]          # logical (M, K)
@@ -93,25 +90,15 @@ class SparseMeta:
                                     # (device_sparse: rebuilt every step,
                                     # static capacity, ``auto`` is static)
 
-    @property
-    def row_loop_sched_len(self) -> int:
-        """Length of the ``row_loop`` backend's static schedule (grid
-        entries per N-tile): ``n_block_rows * max_bpr``.  0 when the bound
-        is unknown (dims-only meta).  Reordering that clusters similar
-        rows shrinks ``max_bpr`` and therefore this length — the quantity
-        ``bench_reorder`` reports and the v4 autotune fingerprint keys on.
-        """
-        return self.n_block_rows * max(self.max_bpr, 0)
-
 
 # accepted aliases -> canonical SpmmConfig.backend strings
 _BACKEND_ALIASES = {"nnz_stream": "pallas"}
-BACKENDS = ("pallas", "row_loop", "xla", "dense")
+BACKENDS = ("pallas", "xla", "dense")
 
 
 @dataclasses.dataclass(frozen=True)
 class SpmmConfig:
-    backend: str = "pallas"         # pallas | row_loop | xla | dense
+    backend: str = "pallas"         # pallas | xla | dense
     bn: int = 512                   # N-tile width for the Pallas grid
     interpret: bool = False
     out_dtype: Optional[str] = None
@@ -211,10 +198,9 @@ def prepare_sparse(a: bcsr_lib.BCSR, dtype=jnp.bfloat16, *,
     stacked leaf shapes must be static and zero blocks stay trainable).
 
     The returned ``meta`` carries the POST-reorder structure stats
-    (``max_bpr``, padding, skew) — the autotune fingerprint and the
-    ``row_loop`` static schedule are both derived from the permuted
-    structure, so clustering that densifies block-rows shrinks the
-    schedule (``meta.row_loop_sched_len``).
+    (``max_bpr``, padding, skew) — the autotune fingerprint is derived
+    from the permuted structure, so clustering that densifies block-rows
+    shrinks ``max_bpr``.
 
     Example (a block-diagonal 32x32 with 8x8 blocks):
 
@@ -224,8 +210,8 @@ def prepare_sparse(a: bcsr_lib.BCSR, dtype=jnp.bfloat16, *,
     >>> dense = np.kron(np.eye(4, dtype=np.float32), np.ones((8, 8)))
     >>> a = bcsr_lib.from_dense(dense.astype(np.float32), (8, 8))
     >>> arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32)
-    >>> (meta.nnzb, meta.max_bpr, meta.row_loop_sched_len)
-    (4, 1, 4)
+    >>> (meta.nnzb, meta.max_bpr)
+    (4, 1)
     """
     from repro.obs import metrics as obs_metrics
     from repro.obs import trace as obs_trace
@@ -262,7 +248,7 @@ def prepare_sparse_meta(a: bcsr_lib.BCSR, *, reorder: str = "identity",
     This is the backbone of the static structure-metadata pipeline: model
     layers re-derive the true post-reorder stats of a deterministic weight
     pattern at trace time (``core.sparse_linear.sparse_linear_meta``
-    memoizes it), so ``backend="auto"`` and ``row_loop`` dispatch on real
+    memoizes it), so ``backend="auto"`` dispatches on real
     ``max_bpr``/padding/skew instead of dims-only zeros."""
     return _prepare_sparse_host(
         a, reorder=reorder, reorder_granularity=reorder_granularity,
@@ -367,47 +353,6 @@ def _pad_b(b: jnp.ndarray, w: int, bn: int):
     return b, N
 
 
-def _sddmm_row_loop_schedule(row_ids: jnp.ndarray, col_ids: jnp.ndarray,
-                             n_block_rows: int, max_bpr: int):
-    """Traced (flat_idx, flat_col) for the static-schedule SDDMM kernel:
-    per (row, slot), the OUTPUT entry index and block-col.  Padding slots
-    point at the sentinel entry ``nnzb`` (the kernel computes and discards
-    their product — the static waste the ``row_loop`` family pays)."""
-    nnzb = row_ids.shape[0]
-    ones = jnp.ones((nnzb,), jnp.int32)
-    row_len = jax.ops.segment_sum(ones, row_ids, num_segments=n_block_rows)
-    rowptr = jnp.concatenate([jnp.zeros((1,), row_len.dtype),
-                              jnp.cumsum(row_len)])
-    slot = jnp.arange(nnzb, dtype=jnp.int32) - rowptr[row_ids].astype(jnp.int32)
-    pos = row_ids * max_bpr + slot
-    flat_idx = jnp.full((n_block_rows * max_bpr,), nnzb, jnp.int32
-                        ).at[pos].set(jnp.arange(nnzb, dtype=jnp.int32))
-    flat_col = jnp.zeros((n_block_rows * max_bpr,), jnp.int32
-                         ).at[pos].set(col_ids)
-    return flat_idx, flat_col
-
-
-def _row_loop_schedule(row_ids: jnp.ndarray, col_ids: jnp.ndarray,
-                       n_block_rows: int, max_bpr: int):
-    """Traced (jnp) version of ``make_row_loop_schedule``: builds the padded
-    (flat_idx, flat_col, row_len) arrays from the sorted row-major entry
-    list, so the static-schedule kernel is dispatchable straight from
-    ``SparseArrays`` (inside jit, no host BCSR needed).  Padding slots point
-    at entry 0 / column 0, matching the host builder."""
-    nnzb = row_ids.shape[0]
-    ones = jnp.ones((nnzb,), jnp.int32)
-    row_len = jax.ops.segment_sum(ones, row_ids, num_segments=n_block_rows)
-    rowptr = jnp.concatenate([jnp.zeros((1,), row_len.dtype),
-                              jnp.cumsum(row_len)])
-    slot = jnp.arange(nnzb, dtype=jnp.int32) - rowptr[row_ids].astype(jnp.int32)
-    pos = row_ids * max_bpr + slot
-    flat_idx = jnp.zeros((n_block_rows * max_bpr,), jnp.int32
-                         ).at[pos].set(jnp.arange(nnzb, dtype=jnp.int32))
-    flat_col = jnp.zeros((n_block_rows * max_bpr,), jnp.int32
-                         ).at[pos].set(col_ids)
-    return flat_idx, flat_col, row_len.astype(jnp.int32)
-
-
 def _fwd_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
               b: jnp.ndarray) -> jnp.ndarray:
     h, w = meta.block
@@ -421,19 +366,6 @@ def _fwd_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
         if cfg.backend == "pallas":
             out = pk.bcsr_spmm_nnz_stream(
                 arrays.vals, arrays.row_ids, arrays.col_ids, b_p,
-                meta.n_block_rows, bn=bn, out_dtype=out_dtype,
-                interpret=cfg.interpret)
-        elif cfg.backend == "row_loop":
-            if meta.max_bpr <= 0:
-                raise ValueError(
-                    "backend='row_loop' needs meta.max_bpr > 0 (metas built "
-                    "by prepare_sparse have it; hand-built specs metas do "
-                    "not)")
-            flat_idx, flat_col, row_len = _row_loop_schedule(
-                arrays.row_ids, arrays.col_ids, meta.n_block_rows,
-                meta.max_bpr)
-            out = pk.bcsr_spmm_row_loop(
-                arrays.vals, flat_idx, flat_col, row_len, b_p,
                 meta.n_block_rows, bn=bn, out_dtype=out_dtype,
                 interpret=cfg.interpret)
         elif cfg.backend == "xla":
@@ -469,9 +401,7 @@ def _dx_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
     with jax.named_scope("smat.pad"):
         g_p, N = _pad_b(g, h, bn)
     bn = min(bn, g_p.shape[1])
-    # row_loop is a forward-schedule choice; the backward always streams the
-    # transpose structure (whose row skew differs from A's).
-    if cfg.backend in ("pallas", "row_loop"):
+    if cfg.backend == "pallas":
         with jax.named_scope("smat.kernel.pallas"):
             out = pk.bcsr_spmm_nnz_stream(
                 t_vals, arrays.t_row_ids, arrays.t_col_ids, g_p,
@@ -493,17 +423,17 @@ def _sddmm_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
     prepared with a reorder; callers pass X in ORIGINAL row order).
 
     Backends mirror the SpMM family: ``pallas`` streams the stored blocks
-    by row panel, ``row_loop`` runs the static (block-row x slot) schedule,
-    ``xla`` is the gather/einsum oracle, ``dense`` materializes the full
-    X @ Y^T and gathers blocks.  Padding entries (``real_mask`` False) are
-    zeroed — they are structural, not values."""
+    by row panel, ``xla`` is the gather/einsum oracle, ``dense``
+    materializes the full X @ Y^T and gathers blocks.  Padding entries
+    (``real_mask`` False) are zeroed — they are structural, not
+    values."""
     h, w = meta.block
     if meta.reorder != "identity" and arrays.row_perm is not None:
         with jax.named_scope("smat.permute"):
             x = jnp.take(x, arrays.row_perm, axis=0)
     out_dtype = jnp.dtype(cfg.out_dtype) if cfg.out_dtype else x.dtype
     # the streamed kernel contracts over all of N (or chunks of its own
-    # choosing), so it pads N to whole lanes only; the others tile N by bn
+    # choosing), so it pads N to whole lanes only; the oracles pad N to bn
     bn = 128 if cfg.backend == "pallas" else cfg.bn
     bn = _clamp_bn(bn, max(x.shape[1], y.shape[1]))
     with jax.named_scope("smat.pad"):
@@ -512,24 +442,11 @@ def _sddmm_impl(cfg: SpmmConfig, meta: SparseMeta, arrays: SparseArrays,
         n_pad = max(x_p.shape[1], y_p.shape[1])
         x_p = jnp.pad(x_p, ((0, 0), (0, n_pad - x_p.shape[1])))
         y_p = jnp.pad(y_p, ((0, 0), (0, n_pad - y_p.shape[1])))
-    bn = min(bn, n_pad)
     with jax.named_scope(f"smat.kernel.{cfg.backend}"):
         if cfg.backend == "pallas":
             vals = pk.bcsr_sddmm(x_p, y_p, arrays.row_ids, arrays.col_ids,
                                  h, w, out_dtype=out_dtype,
                                  interpret=cfg.interpret)
-        elif cfg.backend == "row_loop":
-            if meta.max_bpr <= 0:
-                raise ValueError(
-                    "backend='row_loop' needs meta.max_bpr > 0 (metas built "
-                    "by prepare_sparse have it; hand-built specs metas do "
-                    "not)")
-            flat_idx, flat_col = _sddmm_row_loop_schedule(
-                arrays.row_ids, arrays.col_ids, meta.n_block_rows,
-                meta.max_bpr)
-            vals = pk.bcsr_sddmm_row_loop(
-                x_p, y_p, flat_idx, flat_col, meta.n_block_rows, meta.nnzb,
-                h, w, bn=bn, out_dtype=out_dtype, interpret=cfg.interpret)
         elif cfg.backend == "xla":
             vals = ref.bcsr_sddmm_ref(x_p, y_p, arrays.row_ids,
                                       arrays.col_ids, h, w,
@@ -645,20 +562,10 @@ def resolve_backend(backend: str, bn: int, meta: SparseMeta,
         from repro.kernels import autotune  # local import: avoids cycle
         choice = autotune.get_autotuner().pick(meta, n, op=op)
         backend, bn = choice.backend, choice.bn
-        if backend == "row_loop" and meta.max_bpr <= 0:
-            backend = "pallas"  # stale cached pick for a specs meta
     backend = _BACKEND_ALIASES.get(backend, backend)
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; want one of "
                          f"{BACKENDS + ('auto', 'nnz_stream')}")
-    if backend == "row_loop" and meta.max_bpr <= 0:
-        # explicit request we cannot honor — raising beats silently timing
-        # a different kernel than the caller asked for
-        raise ValueError(
-            "backend='row_loop' needs meta.max_bpr > 0 (metas built by "
-            "prepare_sparse / prepare_sparse_meta have it; dims-only "
-            "specs metas do not — pass sparse_linear_specs a seed, or "
-            "use the model path's sparse_linear_meta)")
     if os.environ.get("REPRO_VERIFY_LAUNCH") == "1":
         # opt-in pre-launch contract check: meta invariants, schedule
         # capacity, and the VMEM budget, all symbolic (repro.analysis)
@@ -773,19 +680,3 @@ def sddmm(arrays: SparseArrays, meta: SparseMeta, x: jnp.ndarray,
     rest = tuple(arrays[1:])
     return _sddmm(cfg, meta, x, y, rest)
 
-
-def make_row_loop_schedule(a: bcsr_lib.BCSR):
-    """Host-side padded (flat_idx, flat_col, row_len, max_bpr) for the
-    paper-faithful static kernel."""
-    bpr = a.blocks_per_row()
-    nbr = a.n_block_rows
-    max_bpr = max(int(bpr.max()) if bpr.size else 1, 1)
-    flat_idx = np.zeros(nbr * max_bpr, dtype=np.int32)
-    flat_col = np.zeros(nbr * max_bpr, dtype=np.int32)
-    for i in range(nbr):
-        s0, s1 = int(a.rowptr[i]), int(a.rowptr[i + 1])
-        flat_idx[i * max_bpr: i * max_bpr + (s1 - s0)] = np.arange(
-            s0, s1, dtype=np.int32)
-        flat_col[i * max_bpr: i * max_bpr + (s1 - s0)] = a.col_ids[s0:s1]
-    return (jnp.asarray(flat_idx), jnp.asarray(flat_col),
-            jnp.asarray(bpr.astype(np.int32)), max_bpr)

@@ -651,9 +651,9 @@ def _combine_splits(out: jnp.ndarray, out_pad: jnp.ndarray,
 def _resolve_shard_choices(smeta: ShardedMeta, n_local: int, backend: str,
                            bn: int) -> Tuple[Tuple[str, int], ...]:
     """Per-shard (backend, bn): ``auto`` consults the v7 per-shard
-    fingerprints, so a skewed shard can run ``row_loop`` while its uniform
-    neighbors stream nonzeros — the per-structure choice the global
-    dispatch could not make.  ``n_local`` is the panel width each shard
+    fingerprints, so a skewed shard can take another variant than its
+    uniform neighbors — the per-structure choice the global dispatch
+    could not make.  ``n_local`` is the panel width each shard
     ACTUALLY multiplies (full N in local mode; N / col_shards under the 2D
     shard_map) so cached picks come from the right N bucket."""
     return tuple(ops.resolve_backend(backend, bn, m, n_local)
@@ -662,8 +662,8 @@ def _resolve_shard_choices(smeta: ShardedMeta, n_local: int, backend: str,
 
 def _branch_meta(smeta: ShardedMeta, members) -> ops.SparseMeta:
     """Representative meta for one switch branch: shapes are shared by
-    construction; max_bpr takes the branch max so a row_loop schedule
-    covers every member shard."""
+    construction; max_bpr takes the branch max, the bound of every member
+    shard."""
     first = smeta.shard_metas[members[0]]
     return dataclasses.replace(
         first, max_bpr=max(smeta.shard_metas[i].max_bpr for i in members))

@@ -308,22 +308,14 @@ def _composed_heads(qf: jnp.ndarray, kf: jnp.ndarray, vf: jnp.ndarray,
 @functools.lru_cache(maxsize=None)
 def _fused_inputs(spec: AttnMaskSpec, seq_len: int, block: Tuple[int, int]):
     """Host constants for the fused kernel: the 0/1 element-mask blocks
-    and the static (block-row x slot) schedule (padding slots -> the
-    sentinel index ``nnzb`` — the host twin of
-    ``ops._sddmm_row_loop_schedule``).  Memoized like the other mask
+    and the static (block-row x slot) schedule
+    (``bcsr_attn.static_schedule``).  Memoized like the other mask
     pipelines; numpy, so trace-safe as closed-over constants."""
     arrays, meta = attention_mask_arrays(spec, seq_len, block)
     emask = ((arrays.vals > 0.5) &
              arrays.real_mask[:, None, None]).astype(np.float32)
-    nnzb = arrays.row_ids.shape[0]
-    counts = np.bincount(arrays.row_ids, minlength=meta.n_block_rows)
-    rowptr = np.concatenate([[0], np.cumsum(counts)])
-    slot = np.arange(nnzb) - rowptr[arrays.row_ids]
-    pos = arrays.row_ids * meta.max_bpr + slot
-    flat_idx = np.full(meta.n_block_rows * meta.max_bpr, nnzb, np.int32)
-    flat_col = np.zeros(meta.n_block_rows * meta.max_bpr, np.int32)
-    flat_idx[pos] = np.arange(nnzb, dtype=np.int32)
-    flat_col[pos] = arrays.col_ids
+    flat_idx, flat_col = bcsr_attn.static_schedule(
+        arrays.row_ids, arrays.col_ids, meta.n_block_rows, meta.max_bpr)
     return emask, flat_idx, flat_col, meta
 
 

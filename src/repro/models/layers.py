@@ -591,8 +591,8 @@ def mlp(cfg, p, x, d_ff=None, seed_hints=(0,)):
     were initialized with (every hint sharing this traced body — the
     layer-scan callers in ``models.transformer`` pass the whole stack's
     hints).  That is what gives the model path heterogeneous per-shard
-    autotune picks and real ``row_loop`` schedule bounds instead of the
-    dims-only collapse."""
+    autotune picks and real ``max_bpr`` bounds instead of the dims-only
+    collapse."""
     act = jax.nn.silu if cfg.mlp_act == "silu" else \
         functools.partial(jax.nn.gelu, approximate=True)
     if cfg.ffn_sparsity is not None and "gate" in p:

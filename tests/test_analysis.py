@@ -236,56 +236,16 @@ class TestVerifier:
         findings = vl.run_verify()
         assert findings == [], "\n".join(str(f) for f in findings)
 
-    def test_spmm_corruption_rejected(self):
-        a, meta = _rand_case()
-        fi, fc, rl = vl.spmm_row_loop_schedule_host(
-            a.row_ids, a.col_ids, meta.n_block_rows, meta.max_bpr)
-        assert vl.verify_schedule("spmm", fi, fc, a.row_ids, a.col_ids,
-                                  meta, row_len=rl) == []
-        # slot dropped: the loop mask skips a live entry
-        bad_rl = rl.copy()
-        bad_rl[int(np.argmax(rl))] -= 1
-        assert vl.verify_schedule("spmm", fi, fc, a.row_ids, a.col_ids,
-                                  meta, row_len=bad_rl)
-        # duplicate entry on a live slot (the spmm-family analogue of a
-        # sentinel on a live block: padding value 0 overwrites a slot)
-        live = np.flatnonzero(fi != 0)
-        bad_fi = fi.copy()
-        bad_fi[live[0]] = 0
-        assert vl.verify_schedule("spmm", bad_fi, fc, a.row_ids, a.col_ids,
-                                  meta, row_len=rl)
-
-    def test_sddmm_corruption_rejected(self):
-        a, meta = _rand_case()
-        fi, fc = vl.sddmm_row_loop_schedule_host(
-            a.row_ids, a.col_ids, meta.n_block_rows, meta.max_bpr)
-        assert vl.verify_schedule("sddmm", fi, fc, a.row_ids, a.col_ids,
-                                  meta) == []
-        # sentinel on a live block: one entry is never computed
-        live = np.flatnonzero(fi != meta.nnzb)
-        bad = fi.copy()
-        bad[live[3]] = meta.nnzb
-        assert vl.verify_schedule("sddmm", bad, fc, a.row_ids, a.col_ids,
-                                  meta)
-        # wrong column on a live slot: the kernel would read the wrong
-        # K-panel
-        bad_fc = fc.copy()
-        bad_fc[live[0]] = (bad_fc[live[0]] + 1) % meta.n_block_cols
-        errs = vl.verify_schedule("sddmm", fi, bad_fc, a.row_ids,
-                                  a.col_ids, meta)
-        assert errs and any("col" in e for e in errs)
-
     def test_attn_corruption_rejected(self):
-        """The fused-attention schedule (built exactly as
-        ``models.attention._fused_inputs`` builds it) under the attn
-        family: dropped slot AND sentinel-on-live both rejected."""
+        """The fused-attention schedule (``models.attention._fused_inputs``
+        builds it with ``bcsr_attn.static_schedule``) under the attn
+        family: dropped slot, sentinel-on-live and a wrong column all
+        rejected."""
         from repro.core.attention_mask import banded
         from repro.models import attention as A
         spec, seq = banded(32), 128
         a = A.attention_mask_bcsr(spec, seq, (16, 16))
-        meta = A.attention_mask_meta(spec, seq, (16, 16))
-        fi, fc = vl.sddmm_row_loop_schedule_host(
-            a.row_ids, a.col_ids, meta.n_block_rows, meta.max_bpr)
+        _, fi, fc, meta = A._fused_inputs(spec, seq, (16, 16))
         assert vl.verify_schedule("attn", fi, fc, a.row_ids, a.col_ids,
                                   meta) == []
         live = np.flatnonzero(fi != meta.nnzb)
@@ -297,6 +257,13 @@ class TestVerifier:
         bad[live[1]] = int(fi[live[0]])   # slot dropped (duplicated twin)
         assert vl.verify_schedule("attn", bad, fc, a.row_ids, a.col_ids,
                                   meta)
+        # wrong column on a live slot: the kernel would read the wrong
+        # K/V panel
+        bad_fc = fc.copy()
+        bad_fc[live[0]] = (bad_fc[live[0]] + 1) % meta.n_block_cols
+        errs = vl.verify_schedule("attn", fi, bad_fc, a.row_ids, a.col_ids,
+                                  meta)
+        assert errs and any("col" in e for e in errs)
 
     def test_meta_invariants(self):
         _, meta = _rand_case()
@@ -323,13 +290,16 @@ class TestVerifier:
             96, 64, SparsitySpec(density=0.3, block=(16, 16)))
         assert meta.max_bpr == 0
         assert vl.verify_meta(meta) == []     # dims-only budgets are legal
-        assert vl.verify_launch(meta, "row_loop", n=64)  # but not row_loop
+        # but the fused attention needs a schedule bound
+        assert vl.verify_launch(meta, "fused", n=64, op="attn")
         assert vl.verify_launch(meta, "xla", n=64) == []
 
     def test_vmem_budget(self):
         _, meta = _rand_case()
-        assert vl.verify_launch(meta, "row_loop", n=512) == []
-        errs = vl.verify_launch(meta, "row_loop", n=512, vmem_budget=1024)
+        # the fused attention: running state and one K/V cell
+        assert vl.verify_launch(meta, "fused", n=64, op="attn") == []
+        errs = vl.verify_launch(meta, "fused", n=64, op="attn",
+                                vmem_budget=1024)
         assert errs and any("VMEM" in e for e in errs)
         # the pallas kernel: accumulator, output tile and DMA ring
         assert vl.verify_launch(meta, "pallas", n=512) == []
@@ -393,13 +363,13 @@ class TestVerifier:
     def test_resolve_backend_hook(self, monkeypatch):
         a, meta = _rand_case()
         monkeypatch.setenv("REPRO_VERIFY_LAUNCH", "1")
-        assert ops.resolve_backend("row_loop", 512, meta, 64) == \
-            ("row_loop", 512)
+        assert ops.resolve_backend("pallas", 512, meta, 64) == \
+            ("pallas", 512)
         bad = dataclasses.replace(meta, nnzb=meta.nnzb * 100)
         with pytest.raises(vl.LaunchError):
-            ops.resolve_backend("row_loop", 512, bad, 64)
+            ops.resolve_backend("pallas", 512, bad, 64)
         monkeypatch.delenv("REPRO_VERIFY_LAUNCH")
-        ops.resolve_backend("row_loop", 512, bad, 64)   # opt-in: no check
+        ops.resolve_backend("pallas", 512, bad, 64)   # opt-in: no check
 
 
 # ======================================================== shared estimators

@@ -17,7 +17,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import autotune, bcsr_attn, ops
+from repro.kernels import autotune, bcsr_attn
 from repro.models import attention as A
 
 
@@ -182,7 +182,7 @@ def test_auto_backend_selects_fused_and_matches():
 
 def test_explicit_and_sharded_specs_stay_composed():
     mask = A.banded(24)
-    for backend in ("xla", "pallas", "row_loop", "dense"):
+    for backend in ("xla", "pallas", "dense"):
         spec = A.AttnSparsitySpec(mask=mask, block=(8, 8), backend=backend)
         assert A.resolve_attn_impl(spec, 64, 8) == "composed"
     sharded = A.AttnSparsitySpec(mask=mask, block=(8, 8), backend="fused",
@@ -219,16 +219,14 @@ def test_fused_under_jit_and_report_fields():
 
 
 def test_fused_schedule_matches_ops_row_loop_schedule():
-    """The host schedule the fused path memoizes must be the exact
-    (flat_idx, flat_col) layout ``ops._sddmm_row_loop_schedule`` builds —
-    one schedule contract across the composed and fused kernels."""
+    """The host schedule the fused path memoizes passes the launch
+    verifier's schedule contract: every stored block in exactly one live
+    slot of its block-row, the sentinel entry only on padding."""
+    from repro.analysis import verify_launch as vl
     arrays, meta = A.attention_mask_arrays(A.local_global(16, 8), 61, (8, 8))
     emask, flat_idx, flat_col, meta2 = A._fused_inputs(
         A.local_global(16, 8), 61, (8, 8))
     assert meta2 == meta
-    ref_idx, ref_col = ops._sddmm_row_loop_schedule(
-        jnp.asarray(arrays.row_ids), jnp.asarray(arrays.col_ids),
-        meta.n_block_rows, meta.max_bpr)
-    np.testing.assert_array_equal(flat_idx, np.asarray(ref_idx))
-    np.testing.assert_array_equal(flat_col, np.asarray(ref_col))
+    assert vl.verify_schedule("attn", flat_idx, flat_col, arrays.row_ids,
+                              arrays.col_ids, meta) == []
     assert emask.shape == arrays.vals.shape

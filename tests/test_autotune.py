@@ -30,8 +30,7 @@ def _mk(seed=0, shape=(96, 128), block=(16, 16), density=0.3):
 # ------------------------------------------------------------------ registry
 def test_registry_has_all_variants():
     names = autotune.variant_names()
-    for want in ("nnz_stream", "row_loop", "xla", "dense"):
-        assert want in names
+    assert names == ("nnz_stream", "xla", "dense")
     for n in names:
         v = autotune.get_variant(n)
         assert v.backend in ops.BACKENDS
@@ -73,11 +72,13 @@ def test_analytic_choice_is_registered_and_supported():
 
 
 def test_analytic_choice_skips_row_loop_without_max_bpr():
-    # hand-built meta (specs path): max_bpr unknown
+    # hand-built meta (specs path): max_bpr unknown, so the fused
+    # attention, whose static schedule max_bpr sizes, is never proposed
     meta = ops.SparseMeta(shape=(128, 128), block=(16, 16), n_block_rows=8,
                           n_block_cols=8, nnzb=16, nnzb_t=16)
-    c = autotune.analytic_choice(meta, 128)
-    assert c.variant != "row_loop"
+    assert not autotune.get_variant("attn_fused").supported(meta)
+    c = autotune.analytic_choice(meta, 128, op="attn")
+    assert c.variant == "attn_composed"
 
 
 def test_pick_caches_in_memory():
@@ -127,15 +128,15 @@ def _failing_backend(monkeypatch, backend):
 
 def test_tune_records_failed_candidates(monkeypatch):
     from repro.obs import trace
-    _failing_backend(monkeypatch, "row_loop")
+    _failing_backend(monkeypatch, "dense")
     tuner = autotune.Autotuner()
     a = _mk(seed=2, shape=(128, 128), density=0.2)
     with trace.capture() as cap:
         choice, timings = tuner.tune(a, 64, iters=1, interpret=True)
     failed = {k for k, v in timings.items() if isinstance(v, str)}
-    assert failed and all(k.startswith("row_loop/") for k in failed)
+    assert failed and all(k.startswith("dense/") for k in failed)
     assert all(timings[k] == "failed: NotImplementedError" for k in failed)
-    assert choice.source == "measured" and choice.variant != "row_loop"
+    assert choice.source == "measured" and choice.variant != "dense"
     events = {e.args["candidate"] for e in cap.events
               if e.name == "autotune.candidate_failed"}
     assert events == failed
@@ -190,30 +191,6 @@ def test_spmm_auto_uses_measured_cache_entry():
     assert bn == choice.bn
 
 
-def test_spmm_row_loop_matches_oracle_and_grads():
-    a = _mk(seed=6, shape=(64, 96), density=0.4)
-    arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32)
-    assert meta.max_bpr > 0
-    b = jnp.asarray(np.random.default_rng(7).standard_normal(
-        (a.shape[1], 32)).astype(np.float32))
-    want = ops.spmm(arrays, meta, b, backend="xla")
-    got = ops.spmm(arrays, meta, b, backend="row_loop", bn=32,
-                   interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-
-    def loss(vals, bb, be):
-        arr = ops.SparseArrays(vals, *arrays[1:])
-        return jnp.sum(ops.spmm(arr, meta, bb, backend=be, bn=32,
-                                interpret=True) ** 2)
-
-    g_rl = jax.grad(loss, argnums=(0, 1))(arrays.vals, b, "row_loop")
-    g_x = jax.grad(loss, argnums=(0, 1))(arrays.vals, b, "xla")
-    for got_g, want_g in zip(g_rl, g_x):
-        np.testing.assert_allclose(np.asarray(got_g), np.asarray(want_g),
-                                   rtol=1e-3, atol=1e-3)
-
-
 def test_backend_alias_and_unknown():
     a = _mk(seed=8)
     arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32)
@@ -225,12 +202,14 @@ def test_backend_alias_and_unknown():
 def test_explicit_row_loop_without_max_bpr_raises():
     meta = ops.SparseMeta(shape=(128, 128), block=(16, 16), n_block_rows=8,
                           n_block_cols=8, nnzb=16, nnzb_t=16)
-    # explicit request cannot be honored -> loud failure, not a silent
-    # switch to a different kernel than the caller asked to measure
-    with pytest.raises(ValueError, match="max_bpr"):
+    # `row_loop` is no backend: an explicit request fails loudly, never a
+    # silent switch to a kernel the caller did not ask for
+    with pytest.raises(ValueError, match="unknown backend"):
         ops.resolve_backend("row_loop", 512, meta, 128)
-    # auto never proposes it for such metas (supported() gate)
-    assert ops.resolve_backend("auto", 512, meta, 128)[0] != "row_loop"
+    # auto resolves inside the backend list, for both ops
+    for op in ("spmm", "sddmm"):
+        assert ops.resolve_backend("auto", 512, meta, 128,
+                                   op=op)[0] in ops.BACKENDS
 
 
 # -------------------------------------------------------- SparsitySpec wiring

@@ -172,7 +172,7 @@ def test_fingerprint_shard_count_no_alias():
     k_shard = autotune.fingerprint(smeta.shard_metas[0], 64).key()
     assert k_full.startswith("v7|") and k_shard.startswith("v7|")
     assert "ns=1" in k_full and "ns=4" in k_shard
-    # the key carries the row_loop schedule bound (v4 field) — real stats
+    # the key carries the max_bpr schedule bound (v4 field) — real stats
     # on both sides
     assert f"mb={meta.max_bpr}" in k_full and meta.max_bpr > 0
     assert k_full != k_shard

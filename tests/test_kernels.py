@@ -1,10 +1,13 @@
 """Per-kernel validation: Pallas (interpret mode) vs the pure-jnp oracle,
 swept over shapes, block sizes, densities and dtypes."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.analysis.verify_launch import structure_zoo
 from repro.core import bcsr as bcsr_lib
 from repro.kernels import bcsr_spmm as pk
 from repro.kernels import ops, ref
@@ -116,15 +119,18 @@ _TPU_INTERPRET = dict(dma_execution_mode="on_wait",
 
 
 @pytest.mark.parametrize("name", ["one_block_per_row",
-                                  "row_longer_than_ring"])
+                                  "row_longer_than_ring",
+                                  "skewed_and_empty_rows"])
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
 def test_nnz_stream_panel_edges(name, dtype):
     """Checked under the TPU interpreter, which runs each DMA only when it
     is waited for and fills uninitialised VMEM with NaN: a block read from
     its ring slot before its copy landed, or overwritten by the next copy,
-    shows in the result."""
+    shows in the result.  The kernel wants every block-row nonempty, so
+    the empty rows of ``skewed_and_empty_rows`` hold a zero block each, as
+    ``prepare_sparse`` pads them."""
     from jax.experimental.pallas import tpu as pltpu
-    a = _panel_edge_case(name)
+    a = _panel_edge_case(name).ensure_nonempty_rows()
     vals = jnp.asarray(a.vals).astype(dtype)
     b = jnp.asarray(np.random.default_rng(17).standard_normal(
         (a.shape[1], 64)).astype(np.float32)).astype(dtype)
@@ -147,38 +153,6 @@ def test_nnz_stream_blocks_per_step_gauge():
     ops.spmm(arrays, meta, b, backend="pallas", bn=128, interpret=True)
     gauges = metrics.snapshot()["gauges"]
     assert gauges["kernel.spmm.blocks_per_step{op=spmm}"] == meta.nnzb / 2
-
-
-@pytest.mark.parametrize("shape,block,density", SHAPES[:3])
-def test_row_loop_matches_ref(shape, block, density):
-    a = _mk(shape, block, density)
-    b = np.random.default_rng(4).standard_normal(
-        (shape[1], 32)).astype(np.float32)
-    flat_idx, flat_col, row_len, max_bpr = ops.make_row_loop_schedule(a)
-    got = pk.bcsr_spmm_row_loop(
-        jnp.asarray(a.vals), flat_idx, flat_col, row_len,
-        jnp.asarray(b), a.n_block_rows, bn=32, interpret=True)
-    want = ref.bcsr_spmm_ref(
-        jnp.asarray(a.vals), jnp.asarray(a.row_ids), jnp.asarray(a.col_ids),
-        jnp.asarray(b), a.n_block_rows)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_row_loop_handles_empty_and_skewed_rows():
-    # adversarial: rows with 0 blocks and one row with many (the dc2 case)
-    rng = np.random.default_rng(5)
-    dense = np.zeros((64, 128), np.float32)
-    dense[3, :] = rng.standard_normal(128)      # very dense row
-    dense[17, 5] = 1.0                           # singleton
-    a = bcsr_lib.from_dense(dense, (8, 16))
-    b = rng.standard_normal((128, 16)).astype(np.float32)
-    flat_idx, flat_col, row_len, _ = ops.make_row_loop_schedule(a)
-    got = pk.bcsr_spmm_row_loop(
-        jnp.asarray(a.vals), flat_idx, flat_col, row_len, jnp.asarray(b),
-        a.n_block_rows, bn=16, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), dense @ b, rtol=1e-4,
-                               atol=1e-4)
 
 
 @pytest.mark.parametrize("shape,block,density", SHAPES[:3] + PANEL_SHAPES)
@@ -299,46 +273,6 @@ def test_sddmm_matches_dense_masked_einsum(shape, block, density, n):
                                          jnp.asarray(a.row_ids),
                                          jnp.asarray(a.col_ids), h, w)
     np.testing.assert_allclose(np.asarray(got_dense), want,
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("shape,block,density", SHAPES[:3])
-def test_sddmm_row_loop_matches_ref(shape, block, density):
-    a = _mk(shape, block, density)
-    rng = np.random.default_rng(12)
-    h, w = block
-    dc = rng.standard_normal((a.n_block_rows * h, 32)).astype(np.float32)
-    b = rng.standard_normal((a.n_block_cols * w, 32)).astype(np.float32)
-    flat_idx, flat_col, _, max_bpr = ops.make_row_loop_schedule(a)
-    # sddmm schedule: padding slots must point at the SENTINEL entry, not 0
-    sched_idx, sched_col = ops._sddmm_row_loop_schedule(
-        jnp.asarray(a.row_ids), jnp.asarray(a.col_ids), a.n_block_rows,
-        max_bpr)
-    got = pk.bcsr_sddmm_row_loop(
-        jnp.asarray(dc), jnp.asarray(b), sched_idx, sched_col,
-        a.n_block_rows, a.nnzb, h, w, bn=32, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), _sddmm_oracle(a, dc, b),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_sddmm_row_loop_skewed_and_empty_rows():
-    # dc2-style skew + empty block-rows: sentinel slots must not clobber
-    # entry 0 (the regression the sentinel output block exists for)
-    rng = np.random.default_rng(13)
-    dense = np.zeros((64, 128), np.float32)
-    dense[3, :] = rng.standard_normal(128)       # one very dense row
-    dense[17, 5] = 1.0                           # singleton
-    a = bcsr_lib.from_dense(dense, (8, 16)).ensure_nonempty_rows()
-    dc = rng.standard_normal((64, 16)).astype(np.float32)
-    b = rng.standard_normal((128, 16)).astype(np.float32)
-    _, _, _, max_bpr = ops.make_row_loop_schedule(a)
-    sched_idx, sched_col = ops._sddmm_row_loop_schedule(
-        jnp.asarray(a.row_ids), jnp.asarray(a.col_ids), a.n_block_rows,
-        max_bpr)
-    got = pk.bcsr_sddmm_row_loop(
-        jnp.asarray(dc), jnp.asarray(b), sched_idx, sched_col,
-        a.n_block_rows, a.nnzb, 8, 16, bn=16, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), _sddmm_oracle(a, dc, b),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -482,7 +416,7 @@ def test_ops_sddmm_ragged_and_empty_rows():
                           ).transpose(0, 2, 1, 3)
     want = blocks[np.asarray(arrays.row_ids), np.asarray(arrays.col_ids)]
     want *= np.asarray(arrays.real_mask)[:, None, None]
-    for backend in ("pallas", "row_loop", "xla", "dense"):
+    for backend in ("pallas", "xla", "dense"):
         got = ops.sddmm(arrays, meta, x, y, backend=backend, bn=64,
                         interpret=True)
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4,
@@ -490,3 +424,39 @@ def test_ops_sddmm_ragged_and_empty_rows():
         pad_rows = ~np.asarray(arrays.real_mask)
         assert pad_rows.any()            # the case genuinely has padding
         assert np.all(np.asarray(got)[pad_rows] == 0.0)
+
+
+# every unsharded structure of the launch verifier's zoo: ragged, wide
+# blocks, skew before and after the Jaccard reorder, the attention masks
+# and a block-row-reordered linear weight
+_ZOO = [c for c in structure_zoo() if not hasattr(c.meta, "shard_metas")]
+
+
+@pytest.mark.parametrize("op", ["spmm", "sddmm"])
+@pytest.mark.parametrize("case", _ZOO, ids=[c.name for c in _ZOO])
+def test_streamed_kernels_match_oracle_on_zoo(case, op):
+    """The streamed Pallas kernels against the ``xla`` oracle through the
+    public ops, on each zoo structure's own entry list: a block the
+    stream skips, visits twice or adds into the wrong rows shows."""
+    meta = case.meta
+    h, w = meta.block
+    rows = jnp.asarray(case.row_ids, jnp.int32)
+    arrays, _ = ops.device_sparse(
+        rows, jnp.asarray(case.col_ids, jnp.int32),
+        jnp.ones(rows.shape, bool), n_block_rows=meta.n_block_rows,
+        n_block_cols=meta.n_block_cols, block=meta.block)
+    rng = np.random.default_rng(21)
+    M, K = meta.shape
+    if op == "spmm":
+        arrays = arrays._replace(vals=jnp.asarray(rng.standard_normal(
+            (meta.nnzb, h, w)).astype(np.float32)))
+        b = jnp.asarray(rng.standard_normal((K, 64)).astype(np.float32))
+        run = functools.partial(ops.spmm, arrays, meta, b, bn=32)
+    else:
+        x = jnp.asarray(rng.standard_normal((M, 64)).astype(np.float32))
+        y = jnp.asarray(rng.standard_normal((K, 64)).astype(np.float32))
+        run = functools.partial(ops.sddmm, arrays, meta, x, y)
+    got = run(backend="pallas", interpret=True)
+    want = run(backend="xla")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

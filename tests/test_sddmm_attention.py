@@ -54,8 +54,7 @@ def _sddmm_dense_oracle(arrays, meta, x, y):
     return samp * np.asarray(arrays.real_mask)[:, None, None]
 
 
-@pytest.mark.parametrize("backend", ["auto", "xla", "pallas", "row_loop",
-                                     "dense"])
+@pytest.mark.parametrize("backend", ["auto", "xla", "pallas", "dense"])
 def test_ops_sddmm_forward(backend):
     a = _mk()
     arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32)
@@ -178,9 +177,8 @@ def test_spmm_and_sddmm_keys_never_alias():
 def test_variant_families_disjoint():
     spmm_names = set(autotune.variant_names("spmm"))
     sddmm_names = set(autotune.variant_names("sddmm"))
-    assert spmm_names == {"nnz_stream", "row_loop", "xla", "dense"}
-    assert sddmm_names == {"sddmm_stream", "sddmm_row_loop", "sddmm_xla",
-                           "sddmm_dense"}
+    assert spmm_names == {"nnz_stream", "xla", "dense"}
+    assert sddmm_names == {"sddmm_stream", "sddmm_xla", "sddmm_dense"}
     attn_names = set(autotune.variant_names("attn"))
     assert attn_names == {"attn_fused", "attn_composed"}
     assert not (spmm_names & sddmm_names) and not (attn_names &

@@ -1,6 +1,6 @@
 """Static structure-metadata pipeline tests (specs-vs-init contract,
-model-path heterogeneous per-shard dispatch, reorder-aware row_loop
-schedules, v6 fingerprints).
+model-path heterogeneous per-shard dispatch, reorder-aware ``max_bpr``,
+v6 fingerprints).
 
 The contract under test: a sparse layer's TRUE structure meta is a pure
 static function of ``(seed, dims, spec)`` — ``sparse_linear_meta`` (and
@@ -187,8 +187,8 @@ def test_model_path_heterogeneous_picks_execute():
 
 def test_stacked_model_forward_auto_matches_xla():
     """The merged stack meta must be valid for EVERY scanned layer: a full
-    2-layer sparse-FFN forward under backend='auto' (real stats, possibly
-    row_loop) matches the xla-backend forward on the same params."""
+    2-layer sparse-FFN forward under backend='auto' (real stats) matches
+    the xla-backend forward on the same params."""
     cfg0 = dataclasses.replace(get_config("smat-ffn-1.3b:smoke"),
                                dtype="float32")
     spec_auto = dataclasses.replace(cfg0.ffn_sparsity, backend="auto")
@@ -205,34 +205,35 @@ def test_stacked_model_forward_auto_matches_xla():
                                rtol=1e-4, atol=1e-4)
 
 
-# ------------------------------------------------ reorder-aware row_loop
+# ------------------------------------------------ reorder-aware max_bpr
 def test_reorder_strictly_shrinks_row_loop_schedule():
     """Acceptance: on a clustered structure, the jaccard permutation gives
-    a STRICTLY shorter row_loop static schedule than identity order, and
-    the shrunk schedule still computes the right answer."""
+    a STRICTLY smaller ``max_bpr`` than identity order, and the streamed
+    kernel on the reordered structure still computes the right answer."""
     csr = topology.blocked_random(n=512, nnz_target=9000, cluster=16, seed=1)
     a = bcsr_lib.from_scipy(csr, (16, 16))
     m_id = ops.prepare_sparse_meta(a)
     m_ro = ops.prepare_sparse_meta(a, reorder="jaccard")
     assert m_ro.max_bpr < m_id.max_bpr
-    assert m_ro.row_loop_sched_len < m_id.row_loop_sched_len
+    assert m_ro.nnzb <= m_id.nnzb
 
     arrays, meta = ops.prepare_sparse(a, dtype=jnp.float32,
                                       reorder="jaccard")
     assert meta == m_ro       # prepare vs meta-only: bit-identical
     b = jnp.asarray(np.random.default_rng(0).standard_normal(
         (meta.shape[1], 32)).astype(np.float32))
-    y_rl = ops.spmm(arrays, meta, b, backend="row_loop", interpret=True)
+    y_ro = ops.spmm(arrays, meta, b, backend="pallas", interpret=True)
     arr_id, meta_id = ops.prepare_sparse(a, dtype=jnp.float32)
     y_ref = ops.spmm(arr_id, meta_id, b, backend="xla")
-    np.testing.assert_allclose(np.asarray(y_rl), np.asarray(y_ref),
+    np.testing.assert_allclose(np.asarray(y_ro), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-3)
 
 
 def test_fingerprint_carries_schedule_bound():
-    """Two metas identical except for the row_loop schedule bound must not
-    alias in the cache (the mb= field, added in v4), so a shrunk reordered
-    structure never inherits the unshrunk twin's row_loop decision."""
+    """Two metas identical except for the schedule bound ``max_bpr`` must
+    not alias in the cache (the mb= field, added in v4), so a shrunk
+    reordered structure never inherits the unshrunk twin's fused-attention
+    decision."""
     a = bcsr_lib.random_bcsr_exact(0, (256, 256), (16, 16), nnzb=64)
     meta = ops.prepare_sparse_meta(a)
     twin = dataclasses.replace(meta, max_bpr=meta.max_bpr + 1)

@@ -23,7 +23,6 @@ from repro.kernels import bcsr_spmm as pk
 BLOCK = (128, 128)
 M, K, N = 8192, 2048, 512                 # smat FFN width, wide token panel
 NNZB = 103                                 # 10% of the 64 x 16 block grid
-MAX_BPR = 4
 ATTN_LEN, ATTN_BAND, HEADS, HEAD_DIM = 32_768, 4096, 16, 128
 # HPCG 64^3 in 16 x 128 blocks: 262,144 rows, 95,760 stored blocks
 HPCG_BLOCK, HPCG_ROWS, HPCG_NNZB = (16, 128), 262_144, 95_760
@@ -74,7 +73,6 @@ def _kernel_cases(s):
     nbr, nbc = M // BLOCK[0], K // BLOCK[1]
     i32, bf16 = jnp.int32, jnp.bfloat16
     ids = _sds(s, (NNZB,), i32)
-    sched = _sds(s, (nbr * MAX_BPR,), i32)
     vals = _sds(s, (NNZB,) + BLOCK, bf16)
     hpcg_ids = _sds(s, (HPCG_NNZB,), i32)
     hpcg_vals = _sds(s, (HPCG_NNZB,) + HPCG_BLOCK, bf16)
@@ -89,10 +87,6 @@ def _kernel_cases(s):
             functools.partial(pk.bcsr_spmm_nnz_stream, n_block_rows=nbr,
                               bn=N),
             (vals, ids, ids, _sds(s, (K, N), bf16))),
-        "spmm_row_loop": (
-            functools.partial(pk.bcsr_spmm_row_loop, n_block_rows=nbr, bn=N),
-            (vals, sched, sched, _sds(s, (nbr,), i32),
-             _sds(s, (K, N), bf16))),
         "sddmm": (
             functools.partial(pk.bcsr_sddmm, h=BLOCK[0], w=BLOCK[1]),
             (_sds(s, (M, N), bf16), _sds(s, (K, N), bf16), ids, ids)),
@@ -111,15 +105,10 @@ def _kernel_cases(s):
             functools.partial(pk.bcsr_sddmm, h=BLOCK[0], w=BLOCK[1]),
             (_sds(s, (M, 32_768), bf16), _sds(s, (K, 32_768), bf16),
              ids, ids)),
-        "sddmm_row_loop": (
-            functools.partial(pk.bcsr_sddmm_row_loop, n_block_rows=nbr,
-                              nnzb=NNZB, h=BLOCK[0], w=BLOCK[1], bn=N),
-            (_sds(s, (M, N), bf16), _sds(s, (K, N), bf16), sched, sched)),
     }
 
 
-@pytest.mark.parametrize("kernel", ["spmm_nnz_stream", "spmm_row_loop",
-                                    "sddmm", "sddmm_row_loop",
+@pytest.mark.parametrize("kernel", ["spmm_nnz_stream", "sddmm",
                                     "spmm_nnz_stream_hpcg_bn128",
                                     "spmm_nnz_stream_hpcg_bn512",
                                     "sddmm_hpcg_n512", "sddmm_moe_d2048",
